@@ -225,6 +225,7 @@ def run(smoke: bool = False, iters: int = 3,
         "bench": "fused masked-gradient path (kernel + R=16 ridge matrix)",
         "meta": bench_meta(),
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "fused_runner_path": fused_enabled(),
         "devices": len(jax.devices()),
         "kernel": kernel,

@@ -85,7 +85,9 @@ def run() -> None:
              "--arch", "xlstm-350m", "--shape", "train_4k",
              "--tag", "suite-smoke", "--log", log],
             capture_output=True, text=True, timeout=900,
-            env={**os.environ,
+            # a CPU rehearsal: the child must never compete with this
+            # process for the accelerator
+            env={**os.environ, "JAX_PLATFORMS": "cpu",
                  "PYTHONPATH": os.pathsep.join(
                      p for p in (os.environ.get("PYTHONPATH"), "src") if p)})
         wall_us = (time.perf_counter() - t0) * 1e6

@@ -10,11 +10,11 @@ bottleneck, MODEL_FLOPS ratio and memory footprint per combo.
 
 The second reads ``benchmarks.bench_fused``'s kernel records (measured us
 per call + analytic FLOPs and ideal HBM bytes) and prints achieved
-GFLOP/s and GB/s against the backend's nominal peaks, plus the implied
-arithmetic intensity and the roofline-predicted bound.  Interpret-mode
-(CPU emulator) rows are marked — their utilization reflects the Pallas
-interpreter, not the TPU dataflow.  Peaks are nominal per-backend
-defaults, overridable with ``--peak-gflops`` / ``--peak-gbps``.
+GFLOP/s and GB/s against the published peaks of the device the records
+name (``device_kind``), plus the implied arithmetic intensity and the
+roofline-predicted bound.  A device with no published peaks is an error,
+not a default: records from a CPU run have none, and ``--peak-gflops`` /
+``--peak-gbps`` must then both be given explicitly.
 """
 from __future__ import annotations
 
@@ -22,15 +22,20 @@ import argparse
 import json
 import os
 
-# nominal single-chip peaks; override per deployment with the CLI flags.
-# TPU numbers are the v5e spec (bf16 MXU / HBM2e); CPU numbers a typical
-# server core-complex — interpret-mode rows are denominated against them
-# only to make the emulator overhead visible.
+# Published peaks of one chip, keyed by JAX's ``device_kind``.  TPU v5e:
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s.
 PEAKS = {
-    "tpu": {"gflops": 394e3 / 2, "gbps": 819.0},   # f32 ~ half bf16 peak
-    "cpu": {"gflops": 200.0, "gbps": 50.0},
-    "gpu": {"gflops": 19.5e3, "gbps": 900.0},
+    "TPU v5 lite": {"gflops": 197e3, "gbps": 819.0},
 }
+
+
+def device_peaks(device_kind: str | None) -> dict:
+    """The published peaks of ``device_kind``; raises for any other."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; have {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def fused_table(path: str, *, peak_gflops: float | None = None,
@@ -38,10 +43,13 @@ def fused_table(path: str, *, peak_gflops: float | None = None,
     """Achieved-vs-peak rows for every kernel case in BENCH_fused.json."""
     with open(path) as f:
         data = json.load(f)
-    backend = data.get("backend", "cpu")
-    peaks = PEAKS.get(backend, PEAKS["cpu"])
-    pg = peak_gflops or peaks["gflops"]
-    pb = peak_gbps or peaks["gbps"]
+    kind = data.get("device_kind")
+    if peak_gflops and peak_gbps:
+        pg, pb = peak_gflops, peak_gbps
+    else:
+        peaks = device_peaks(kind)
+        pg = peak_gflops or peaks["gflops"]
+        pb = peak_gbps or peaks["gbps"]
     hdr = ["case", "mode", "m", "r", "p", "us", "GFLOP/s", "%peak",
            "GB/s", "%peak_bw", "intensity", "bound"]
     sep = " | " if md else ","
@@ -62,9 +70,7 @@ def fused_table(path: str, *, peak_gflops: float | None = None,
                  f"{100 * gbps / pb:.2f}%", f"{intensity:.1f}", bound]
         line = sep.join(str(c) for c in cells)
         print(("| " + line + " |") if md else line)
-    note = (f"backend={backend} peaks: {pg:.0f} GFLOP/s, {pb:.0f} GB/s"
-            + (" (interpret rows measure the emulator)"
-               if backend != "tpu" else ""))
+    note = f"device_kind={kind} peaks: {pg:.0f} GFLOP/s, {pb:.0f} GB/s"
     print(f"{'<!-- ' if md else '# '}{note}{' -->' if md else ''}")
 
 

@@ -32,7 +32,11 @@ def _record_suite_manifest(suite_rows: list, total_s: float) -> None:
         print(f"# runstore: suite manifest not recorded: {e}")
 
 
-def main() -> None:
+def main() -> int:
+    """Run every suite; returns 1 when any suite failed (after running the
+    rest), else 0."""
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_spectrum, bench_ridge, bench_lasso, bench_logistic,
                    bench_matrix_factorization, bench_kernels, bench_coded_lm,
                    bench_runtime, bench_encoding, bench_trials,
@@ -79,7 +83,8 @@ def main() -> None:
     total_s = time.time() - t_all
     print(f"# total: {total_s:.1f}s")
     _record_suite_manifest(suite_rows, total_s)
+    return int(any(r["status"] != "ok" for r in suite_rows))
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -94,6 +94,8 @@ def main():
                     help="write the comparison summary as JSON (CI hook)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # FRC with rows_per_worker=beta draws the SAME b*rows = m sequences per
     # step as the uncoded run (b = m/beta clusters), so with an exact decode
     # the two trajectories consume identical tokens and match to FP noise.

@@ -34,6 +34,8 @@ from typing import Any
 
 import numpy as np
 
+from repro.runtime.strategies import UnsupportedStrategy
+
 from .io import (print_table, write_json, write_metrics_csv,
                  write_summary_csv, write_trace_csv)
 from .plan import ExperimentPlan, PlannedCell
@@ -155,7 +157,10 @@ def execute(plan: ExperimentPlan, *, record_to=None, retries: int = 0,
             retry_base: float = 0.5,
             resume: str | None = None) -> ExperimentResult:
     """Run every planned cell; never aborts mid-matrix for per-cell
-    incompatibilities (those become skip-with-reason records).
+    incompatibilities (``UnsupportedStrategy``: those become
+    skip-with-reason records).  Any other error raised while tracing,
+    lowering, compiling or running a cell propagates and fails the run —
+    a kernel the chip's compiler refuses is never a skipped cell.
 
     When the spec carries an enabled :class:`ObsAxis`, the whole matrix runs
     under an active :class:`repro.obs.TraceRecorder`: every record gains
@@ -178,7 +183,7 @@ def execute(plan: ExperimentPlan, *, record_to=None, retries: int = 0,
     serialized).
 
     ``retries`` re-runs a cell whose execution RAISED (host crash, OOM —
-    not the in-simulation faults, and not per-cell ``ValueError``
+    not the in-simulation faults, and not ``UnsupportedStrategy``
     incompatibilities, which are already skip records) up to that many
     extra times with capped exponential backoff (``retry_base * 2**i``
     seconds, ±25% deterministic jitter, 30 s cap); the last failure
@@ -454,7 +459,7 @@ def _execute_train_cell(cell: PlannedCell, caches: dict) -> CellOutcome:
                 eval_every=cell.eval_every, placement=cell.placement, **cfg)
         else:
             result = strat.run(spec_, engine, steps=cell.steps, **cfg)
-    except ValueError as e:
+    except UnsupportedStrategy as e:
         print(f"# skipping {cell.resolved_strategy} x {cell.delay}: {e}")
         return CellOutcome(cell, {**base, "skipped": str(e),
                                   "metric_name": "loss"})
@@ -509,7 +514,7 @@ def _execute_synthetic_cell(cell: PlannedCell, caches: dict) -> CellOutcome:
         else:
             result = get_strategy(cell.resolved_strategy).run(
                 spec_, engine, steps=cell.steps, **cfg)
-    except ValueError as e:
+    except UnsupportedStrategy as e:
         print(f"# skipping {cell.resolved_strategy} x {cell.delay}: {e}")
         return CellOutcome(cell, {**base, "skipped": str(e),
                                   "metric_name": "objective"})
@@ -589,7 +594,7 @@ def _execute_cell_group(cells: list, runner: _CellRunner) -> list:
         results = strat.run_cellbatched(
             spec_, engines, steps=cells[0].steps, trials=cells[0].trials,
             eval_every=cells[0].eval_every, cfgs=cfgs)
-    except ValueError as e:
+    except UnsupportedStrategy as e:
         print(f"# cell batch of {len(cells)} "
               f"{cells[0].resolved_strategy} cells fell back to per-cell "
               f"execution: {e}")
@@ -646,7 +651,7 @@ def _workload_data(cell: PlannedCell, wl, ps, caches: dict):
 
 
 def _execute_workload_cell(cell: PlannedCell, caches: dict) -> CellOutcome:
-    from repro.workloads import UnsupportedStrategy, get_workload
+    from repro.workloads import get_workload
     pr, st = cell.problem, cell.strategy
     wl = get_workload(pr.workload)
     ps = wl.preset(pr.preset)
@@ -695,12 +700,9 @@ def _execute_workload_cell(cell: PlannedCell, caches: dict) -> CellOutcome:
                 cell, {**base, **trials_record(results, delay=cell.delay,
                                                seed=cell.seed)}, results)
         result = wl.run(st.name, engine, preset=ps, data=data, **cell_cfg)
-    except ValueError as e:
-        # UnsupportedStrategy (runtime-detected), or a config clash (e.g.
-        # --m below the preset's k) — record the reason, keep the matrix
-        # going (same contract as the synthetic path)
-        if not isinstance(e, UnsupportedStrategy):
-            print(f"# skipping {cell.resolved_strategy} x {cell.delay}: {e}")
+    except UnsupportedStrategy as e:
+        # a runtime-detected incompatibility — record the reason, keep the
+        # matrix going (same contract as the synthetic path)
         return CellOutcome(cell, {**base, "skipped": str(e),
                                   "metric_name": wl.metric_name})
     rec = result.to_record()

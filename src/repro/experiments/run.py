@@ -215,6 +215,8 @@ def main(argv: Sequence[str] | None = None) -> ExperimentResult:
                          "execute split)")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     spec = build_spec(args)
     pl = plan(spec)
     if args.plan_only:

@@ -10,10 +10,11 @@ Grid over P blocks; the worker axis (m <= 32) rides along the sublane dim.
 
 Layout is resolved ONCE per gradient width (``combine_layout``, lru-cached):
 instead of zero-padding P up to a block multiple on every call, the block
-width snaps to the largest divisor of P under the cap, so for any realistic
-P the kernel tiles the array exactly and the per-step pad disappears from
-the traced program.  Padding only survives as a last resort when the best
-divisor is lane-hostile (< 128) — e.g. a large prime P.
+width snaps to the largest multiple-of-128 divisor of P under the cap, so
+for the widths encoders and models produce the kernel tiles the array
+exactly and the per-step pad disappears from the traced program.  Padding
+only survives as a last resort when P has no such divisor — e.g. the ridge
+paper width 6000, or a large prime P.
 """
 from __future__ import annotations
 
@@ -23,25 +24,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .fwht import default_interpret
+from .fwht import LANES, default_interpret
 
 __all__ = ["coded_combine_call", "combine_layout"]
 
 
 @functools.lru_cache(maxsize=None)
 def combine_layout(P: int, block: int = 2048) -> tuple[int, int]:
-    """(block_width, pad) for a width-P combine.  pad == 0 whenever P has a
-    divisor in [128, block] (always true for the power-of-two-ish widths
-    encoders produce) — the pad then never enters the traced program."""
-    bp = min(block, P)
-    if P % bp == 0:
-        return bp, 0
-    d = bp
-    while P % d:
-        d -= 1
-    if d >= 128:
-        return d, 0
-    return bp, (-P) % bp
+    """(block_width, pad) for a width-P combine.  The block is the whole P
+    when P <= block, else a multiple of 128 (the TPU lane tiling rule).
+    pad == 0 whenever P has such a divisor under ``block`` (true for the
+    power-of-two-ish widths encoders produce) — the pad then never enters
+    the traced program.  Otherwise P is padded to whole blocks, with the
+    block sized to waste fewer than 128 columns per block."""
+    if P <= block:
+        return P, 0
+    top = block // LANES * LANES
+    for bp in range(top, 0, -LANES):
+        if P % bp == 0:
+            return bp, 0
+    nblocks = -(-P // top)
+    bp = -(-P // (nblocks * LANES)) * LANES
+    return bp, nblocks * bp - P
 
 
 def _combine_body(g_ref, c_ref, o_ref):

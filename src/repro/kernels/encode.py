@@ -13,9 +13,11 @@ round-trip per tile:
   1. sign-flip: multiply the (BLOCK_ROWS, N) tile by the broadcast sign row
      (zeros kill any stray values in dead lanes — the zero-pad is enforced
      here, not trusted from the caller);
-  2. all log2(N) FWHT butterfly stages in VMEM (same layout as fwht.py);
-  3. row gather: only the contiguous encoded-row window [lo, hi) — a worker
-     block, or the full frame — is scaled and written back to HBM.
+  2. the FWHT in VMEM (same chunked layout as fwht.py);
+  3. row gather: only the output lane chunks covering the encoded-row window
+     [lo, hi) — a worker block, or the full frame — are scaled and written
+     back to HBM.  The kernel writes the chunk-aligned window; the exact
+     [lo, hi) slice happens outside it, so no lane slice is unaligned.
 
 The transform axis is the trailing (lane) axis: callers pass X^T so encoded
 ROWS become output lanes and the gather is a static lane slice.
@@ -28,29 +30,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .fwht import butterfly, default_interpret, pick_block_rows
+from .fwht import butterfly, default_interpret, hadamard_tile, row_blocks
 
 __all__ = ["srht_encode_call"]
 
 
-def _srht_body(x_ref, d_ref, o_ref, *, n: int, lo: int, hi: int,
+def _srht_body(x_ref, d_ref, h_ref, o_ref, *, c0: int, c1: int,
                scale: float):
-    x = butterfly(x_ref[...].astype(jnp.float32) *
-                  d_ref[...].astype(jnp.float32), n)
-    o_ref[...] = (x[:, lo:hi] * scale).astype(o_ref.dtype)
+    chunks = butterfly(x_ref[...].astype(jnp.float32) *
+                       d_ref[...].astype(jnp.float32), h_ref[...])
+    o_ref[...] = (jnp.concatenate(chunks[c0:c1], axis=1) *
+                  scale).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("lo", "hi", "scale",
-                                             "block_rows", "interpret"))
+                                             "interpret"))
 def srht_encode_call(xt: jax.Array, dsigns: jax.Array, *, lo: int, hi: int,
-                     scale: float, block_rows: int | None = None,
-                     interpret: bool | None = None) -> jax.Array:
+                     scale: float, interpret: bool | None = None) -> jax.Array:
     """Fused sign-flip + FWHT + row-window encode.
 
     xt:     (p, N) — data columns as rows, already scattered into the N
             padded transform positions (zeros elsewhere).
     dsigns: (1, N) — random signs at live positions, ZERO at dead ones.
     Returns (p, hi - lo): encoded rows [lo, hi) of S X, transposed.
+    The p rows are zero-padded to whole blocks.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -61,15 +64,20 @@ def srht_encode_call(xt: jax.Array, dsigns: jax.Array, *, lo: int, hi: int,
         raise ValueError(f"row window [{lo}, {hi}) outside [0, {n})")
     if dsigns.shape != (1, n):
         raise ValueError(f"dsigns shape {dsigns.shape} != (1, {n})")
-    br = block_rows or pick_block_rows(rows, n, xt.dtype.itemsize)
-    if rows % br:
-        raise ValueError(f"rows {rows} not divisible by block_rows {br}")
-    return pl.pallas_call(
-        functools.partial(_srht_body, n=n, lo=lo, hi=hi, scale=scale),
-        grid=(rows // br,),
+    br, pad = row_blocks(rows, n)
+    xp = jnp.pad(xt, ((0, pad), (0, 0))) if pad else xt
+    h = hadamard_tile(n)
+    b = h.shape[0]
+    c0, c1 = lo // b, -(-hi // b)                 # chunks covering [lo, hi)
+    out = pl.pallas_call(
+        functools.partial(_srht_body, c0=c0, c1=c1, scale=scale),
+        grid=((rows + pad) // br,),
         in_specs=[pl.BlockSpec((br, n), lambda i: (i, 0)),
-                  pl.BlockSpec((1, n), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((br, hi - lo), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, hi - lo), xt.dtype),
+                  pl.BlockSpec((1, n), lambda i: (0, 0)),
+                  pl.BlockSpec(h.shape, lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((br, (c1 - c0) * b), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows + pad, (c1 - c0) * b),
+                                       xt.dtype),
         interpret=interpret,
-    )(xt, dsigns)
+    )(xp, dsigns, h)
+    return out[:rows, lo - c0 * b:hi - c0 * b]

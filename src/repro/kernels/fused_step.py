@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .fwht import default_interpret
+from .fwht import LANES, default_interpret
 
 __all__ = ["fused_masked_gradient", "fused_enabled", "pick_fused_block_rows"]
 
@@ -47,28 +47,26 @@ def fused_enabled() -> bool:
 
 def pick_fused_block_rows(r: int, p: int, dtype_bytes: int = 4,
                           vmem_budget: int = 8 * 1024 * 1024) -> int:
-    """Largest divisor of the per-worker row count ``r`` whose (br, p) slab
-    plus its pipeline double-buffer fits the VMEM budget.  A divisor means
-    no row padding: the grid tiles ``r`` exactly."""
+    """Row block for the (br, p) slabs of the per-worker row count ``r``:
+    the largest multiple of 128 dividing ``r`` whose slab plus its pipeline
+    double-buffer fits the VMEM budget, else the whole ``r``.  A divisor
+    means no row padding: the grid tiles ``r`` exactly, and either choice
+    meets the TPU's (8, 128) block tiling rule."""
     cap = max(1, vmem_budget // max(1, 2 * p * dtype_bytes))
-    best = 1
-    d = 1
-    while d * d <= r:
-        if r % d == 0:
-            for cand in (d, r // d):
-                if best < cand <= cap:
-                    best = cand
-        d += 1
-    return best
+    for br in range(min(r, cap) // LANES * LANES, 0, -LANES):
+        if r % br == 0:
+            return br
+    return r
 
 
 def _fused_body(sx_ref, sy_ref, w_ref, c_ref, o_ref):
     """Grid (m, r // br): worker i, row block j.
 
     Residual matvec + rank-br gradient contribution + weighted accumulate,
-    all on the current slab.  The (1, p) output block has a constant index
-    map, so it stays pinned in VMEM across every grid step; TPU grid
-    iteration is sequential, so the (0, 0) zero-init runs first.
+    all on the current slab, as exact f32 multiply-reduces on the VPU.  The
+    (1, p) output block has a
+    constant index map, so it stays pinned in VMEM across every grid step;
+    TPU grid iteration is sequential, so the (0, 0) zero-init runs first.
     """
     i, j = pl.program_id(0), pl.program_id(1)
 
@@ -76,14 +74,12 @@ def _fused_body(sx_ref, sy_ref, w_ref, c_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    sx = sx_ref[0].astype(jnp.float32)                      # (br, p)
+    sx = sx_ref[...].astype(jnp.float32)                    # (br, p)
     w = w_ref[...].astype(jnp.float32)                      # (1, p)
-    u = jax.lax.dot_general(w, sx, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    u = u - sy_ref[...].astype(jnp.float32)                 # (1, br) residual
-    g = jax.lax.dot_general(u, sx, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (1, p)
-    o_ref[...] += c_ref[0, 0].astype(jnp.float32) * g
+    u = (jnp.sum(sx * w, axis=1, keepdims=True)
+         - sy_ref[...].astype(jnp.float32))                 # (br, 1) residual
+    g = jnp.sum(u * sx, axis=0, keepdims=True)              # (1, p)
+    o_ref[...] += c_ref[...] * g                            # c: (1, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
@@ -91,17 +87,19 @@ def _fused_call(SX: jax.Array, Sy: jax.Array, w: jax.Array, c: jax.Array, *,
                 interpret: bool, block_rows: int | None = None) -> jax.Array:
     m, r, p = SX.shape
     br = block_rows or pick_fused_block_rows(r, p, SX.dtype.itemsize)
+    # every block's trailing two dims are either whole array dims or
+    # (8, 128)-aligned: Sy rides as a (br, 1) column, c as a (1, 1) tile
     out = pl.pallas_call(
         _fused_body,
         grid=(m, r // br),
-        in_specs=[pl.BlockSpec((1, br, p), lambda i, j: (i, j, 0)),
-                  pl.BlockSpec((1, br), lambda i, j: (i, j)),
+        in_specs=[pl.BlockSpec((None, br, p), lambda i, j: (i, j, 0)),
+                  pl.BlockSpec((None, br, 1), lambda i, j: (i, j, 0)),
                   pl.BlockSpec((1, p), lambda i, j: (0, 0)),
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0))],
+                  pl.BlockSpec((None, 1, 1), lambda i, j: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, p), lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, p), jnp.float32),
         interpret=interpret,
-    )(SX, Sy, w[None, :], c)
+    )(SX, Sy[:, :, None], w[None, :], c.reshape(m, 1, 1))
     return out[0].astype(w.dtype)
 
 

@@ -49,19 +49,9 @@ def srht_encode(X: jax.Array, cols: np.ndarray, signs: np.ndarray, N: int,
     xt = jnp.zeros((p, N), X.dtype).at[:, jnp.asarray(cols)].set(X.T)
     dsigns = jnp.zeros((1, N), X.dtype).at[0, jnp.asarray(cols)].set(
         jnp.asarray(signs, X.dtype))
-    # pad the grid axis (data columns) up to a whole number of row blocks:
-    # the budget-limited block (pick_block_rows with an always-divisible row
-    # count) capped at the next power of two covering p
-    from .fwht import pick_block_rows
-    br = min(pick_block_rows(1 << 30, N, xt.dtype.itemsize),
-             1 << max(p - 1, 1).bit_length())
-    pad = (-p) % br
-    if pad:
-        xt = jnp.pad(xt, ((0, pad), (0, 0)))
     out = srht_encode_call(xt, dsigns, lo=lo, hi=hi,
-                           scale=1.0 / math.sqrt(n), block_rows=br,
-                           interpret=not on_tpu())
-    return out[:p].T
+                           scale=1.0 / math.sqrt(n), interpret=not on_tpu())
+    return out.T
 
 
 def hadamard_encode(X: jax.Array, cols: np.ndarray, signs: np.ndarray,

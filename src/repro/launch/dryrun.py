@@ -100,8 +100,6 @@ def dryrun_one(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):       # jax<=0.4.x wraps in a list
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     hlo_totals = analyze_hlo(hlo_text)
     if hotspots:

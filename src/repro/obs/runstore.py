@@ -66,7 +66,7 @@ def git_sha(cwd: str | None = None) -> str:
 def provenance() -> dict:
     """The environment stamp every manifest (and ``BENCH_*.json``, via
     ``benchmarks.common.bench_meta``) carries: git sha, backend, jax
-    version, device count, ISO-8601 UTC timestamp."""
+    version, device count and kind, ISO-8601 UTC timestamp."""
     meta = {
         "git_sha": git_sha(),
         "timestamp": datetime.now(timezone.utc).isoformat(
@@ -77,9 +77,10 @@ def provenance() -> dict:
         meta["backend"] = jax.default_backend()
         meta["jax_version"] = jax.__version__
         meta["device_count"] = jax.device_count()
+        meta["device_kind"] = jax.devices()[0].device_kind
     except Exception:
         meta.update(backend="unavailable", jax_version="unavailable",
-                    device_count=0)
+                    device_count=0, device_kind="unavailable")
     return meta
 
 

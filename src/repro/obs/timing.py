@@ -17,17 +17,15 @@ from __future__ import annotations
 
 import time
 
+import jax
+
 __all__ = ["block", "time_us", "emit", "CompileWatch"]
 
 
 def block(out):
-    """``jax.block_until_ready`` on every leaf of ``out``; no-op for host
-    values (and for environments without jax)."""
-    try:
-        import jax
-        return jax.block_until_ready(out)
-    except Exception:
-        return out
+    """``jax.block_until_ready`` on every leaf of ``out``; host values pass
+    through.  A device error surfacing here propagates."""
+    return jax.block_until_ready(out)
 
 
 def time_us(fn, *args, iters: int = 5, warmup: int = 1, **kw) -> float:
@@ -61,7 +59,7 @@ _COMPILE_KEYS = (
     _COMPILE_EVENT,
 )
 
-_STATE = {"secs": 0.0, "compiles": 0, "registered": False, "available": True}
+_STATE = {"secs": 0.0, "compiles": 0, "registered": False}
 
 
 def _on_event(event: str, duration_secs: float, **kw) -> None:
@@ -71,21 +69,11 @@ def _on_event(event: str, duration_secs: float, **kw) -> None:
             _STATE["compiles"] += 1
 
 
-def _ensure_listener() -> bool:
+def _ensure_listener() -> None:
     """Register the (process-global, idempotent) compile listener."""
-    if _STATE["registered"]:
-        return True
-    if not _STATE["available"]:
-        return False
-    try:
-        import jax.monitoring
+    if not _STATE["registered"]:
         jax.monitoring.register_event_duration_secs_listener(_on_event)
         _STATE["registered"] = True
-        return True
-    except Exception:
-        # old/stripped jax: compile_s degrades to 0 rather than breaking
-        _STATE["available"] = False
-        return False
 
 
 class CompileWatch:

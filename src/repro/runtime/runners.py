@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core.data_parallel import (EncodedProblem, masked_gradient,
@@ -565,8 +564,11 @@ def _sharded_fn(kind: str, ndev: int, h: str, eval_every: int,
         in_specs = (P, Pt, Pt, P, Pt)
     else:
         raise KeyError(f"unknown sharded runner kind '{kind}'")
-    return jax.jit(shard_map(impl, mesh=mesh, in_specs=in_specs,
-                             out_specs=(Pt, Pt), check_rep=False))
+    # check_vma=False: the Pallas kernels on the step path mix replicated
+    # operands (SX) with per-realization ones (w) inside their bodies,
+    # which the varying-axes check cannot follow through a pallas_call
+    return jax.jit(jax.shard_map(impl, mesh=mesh, in_specs=in_specs,
+                                 out_specs=(Pt, Pt), check_vma=False))
 
 
 def sharded_scan_gd(prob: EncodedProblem, masks: jax.Array, step_size,

@@ -35,9 +35,18 @@ from .runners import (batched_scan_async, batched_scan_bcd, batched_scan_gd,
 
 __all__ = [
     "ProblemSpec", "RunResult", "TrialsResult", "Strategy",
+    "UnsupportedStrategy",
     "register_strategy", "get_strategy", "available_strategies",
     "json_safe_meta", "summary_stats", "check_trials", "resolve_eval_every",
 ]
+
+
+class UnsupportedStrategy(ValueError):
+    """A planned incompatibility: this strategy cannot run this cell's
+    problem or configuration (an l1 objective for a smooth-only scheme, a
+    degrade mode the scheme has no state for, a mixed cell batch).  The one
+    error that harnesses turn into a skip-with-reason record or a per-cell
+    fallback — every other error fails the run."""
 
 
 def json_safe_meta(meta: dict) -> dict:
@@ -473,7 +482,7 @@ class _SyncGradientStrategy(Strategy):
         stride_every = resolve_eval_every(steps, eval_every)
         ms = {e.m for e in engines}
         if len(ms) > 1:
-            raise ValueError(f"cell batch mixes worker counts {sorted(ms)}")
+            raise UnsupportedStrategy(f"cell batch mixes worker counts {sorted(ms)}")
         policies = [self._policy(e, cfg) for e, cfg in zip(engines, cfgs)]
         degrades = [_resolve_degrade(pol, cfg)
                     for pol, cfg in zip(policies, cfgs)]
@@ -481,7 +490,7 @@ class _SyncGradientStrategy(Strategy):
         # program, so a batch must be degrade-homogeneous (the executor's
         # compat key groups on the degrade spec — this is a backstop)
         if len({d for d in degrades}) > 1:
-            raise ValueError("cell batch mixes degrade policies "
+            raise UnsupportedStrategy("cell batch mixes degrade policies "
                              f"{sorted({str(d) for d in degrades})}")
         degrade = degrades[0]
         enc, prob = self._problem(spec, engines[0], cfgs[0])
@@ -536,20 +545,20 @@ class CodedProx(_SyncGradientStrategy):
 
     def run(self, spec, engine, *, steps=200, **cfg):
         if spec.h != "l1":
-            raise ValueError("coded-prox requires an l1 ProblemSpec")
+            raise UnsupportedStrategy("coded-prox requires an l1 ProblemSpec")
         return super().run(spec, engine, steps=steps, **cfg)
 
     def run_batched(self, spec, engine, *, steps=200, trials=1, eval_every=1,
                     **cfg):
         if spec.h != "l1":
-            raise ValueError("coded-prox requires an l1 ProblemSpec")
+            raise UnsupportedStrategy("coded-prox requires an l1 ProblemSpec")
         return super().run_batched(spec, engine, steps=steps, trials=trials,
                                    eval_every=eval_every, **cfg)
 
     def run_cellbatched(self, spec, engines, *, steps=200, trials=1,
                         eval_every=1, cfgs=None):
         if spec.h != "l1":
-            raise ValueError("coded-prox requires an l1 ProblemSpec")
+            raise UnsupportedStrategy("coded-prox requires an l1 ProblemSpec")
         return super().run_cellbatched(spec, engines, steps=steps,
                                        trials=trials, eval_every=eval_every,
                                        cfgs=cfgs)
@@ -576,11 +585,11 @@ class CodedLBFGS(_SyncGradientStrategy):
 
     def run(self, spec, engine, *, steps=200, **cfg):
         if spec.h != "l2":
-            raise ValueError("coded-lbfgs requires the ridge objective")
+            raise UnsupportedStrategy("coded-lbfgs requires the ridge objective")
         policy = self._policy(engine, cfg)
         degrade = _resolve_degrade(policy, cfg)
         if degrade is not None and degrade.mode == "hold":
-            raise ValueError("coded-lbfgs supports renormalize/backoff "
+            raise UnsupportedStrategy("coded-lbfgs supports renormalize/backoff "
                              "degrade only (the two-loop memory is host "
                              "state; see DESIGN.md §14)")
         enc, prob = self._problem(spec, engine, cfg)
@@ -607,13 +616,13 @@ class CodedLBFGS(_SyncGradientStrategy):
         and the schedule stack are built once, and the trace is strided
         like the fused runners."""
         if spec.h != "l2":
-            raise ValueError("coded-lbfgs requires the ridge objective")
+            raise UnsupportedStrategy("coded-lbfgs requires the ridge objective")
         check_trials(steps, trials, eval_every)
         stride_every = resolve_eval_every(steps, eval_every)
         policy = self._policy(engine, cfg)
         degrade = _resolve_degrade(policy, cfg)
         if degrade is not None and degrade.mode == "hold":
-            raise ValueError("coded-lbfgs supports renormalize/backoff "
+            raise UnsupportedStrategy("coded-lbfgs supports renormalize/backoff "
                              "degrade only (the two-loop memory is host "
                              "state; see DESIGN.md §14)")
         enc, prob = self._problem(spec, engine, cfg)
@@ -654,7 +663,7 @@ class CodedBCD(_SyncGradientStrategy):
         policy = self._policy(engine, cfg)
         degrade = _resolve_degrade(policy, cfg)
         if degrade is not None and degrade.mode == "hold":
-            raise ValueError("coded-bcd supports renormalize/backoff degrade "
+            raise UnsupportedStrategy("coded-bcd supports renormalize/backoff degrade "
                              "only (an erased block simply holds its "
                              "coordinates; see DESIGN.md §14)")
         with _obs_span("encode", strategy=self.name, p=spec.p, m=engine.m):
@@ -691,7 +700,7 @@ class CodedBCD(_SyncGradientStrategy):
         policy = self._policy(engine, cfg)
         degrade = _resolve_degrade(policy, cfg)
         if degrade is not None and degrade.mode == "hold":
-            raise ValueError("coded-bcd supports renormalize/backoff degrade "
+            raise UnsupportedStrategy("coded-bcd supports renormalize/backoff degrade "
                              "only (an erased block simply holds its "
                              "coordinates; see DESIGN.md §14)")
         with _obs_span("encode", strategy=self.name, p=spec.p, m=engine.m):
@@ -782,7 +791,7 @@ class AsyncSGD(Strategy):
 
     def run(self, spec, engine, *, steps=200, **cfg):
         if spec.h == "l1":
-            raise ValueError("async baseline covers smooth objectives only")
+            raise UnsupportedStrategy("async baseline covers smooth objectives only")
         m = engine.m
         # per-arrival accounting has no barrier to degrade: crashed workers
         # simply stop contributing and corrupt arrivals are discarded by
@@ -816,7 +825,7 @@ class AsyncSGD(Strategy):
     def run_batched(self, spec, engine, *, steps=200, trials=1, eval_every=1,
                     placement="vmap", **cfg):
         if spec.h == "l1":
-            raise ValueError("async baseline covers smooth objectives only")
+            raise UnsupportedStrategy("async baseline covers smooth objectives only")
         m = engine.m
         cfg.pop("degrade", None)   # no barrier to degrade (see run())
         bound = int(cfg.pop("staleness_bound", 2 * m))

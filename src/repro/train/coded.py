@@ -44,6 +44,7 @@ from ..obs.timing import CompileWatch, block
 from ..obs.trace import span as _obs_span
 from ..optim import adamw_init, adamw_update, cosine_schedule
 from ..runtime.engine import ClusterEngine, FastestK, _policy_k_min
+from ..runtime.strategies import UnsupportedStrategy
 
 __all__ = ["TrainerConfig", "TrainProblem", "build_coded_train_step",
            "CodedTrainer", "run_coded_sgd"]
@@ -113,7 +114,7 @@ def build_coded_train_step(cfg: ArchConfig, lr_fn: Callable, *,
     they pass through the same (unbiased) combine.
     """
     if cfg.n_patches or cfg.n_enc_layers:
-        raise ValueError("coded-sgd covers token-only LMs (no patch/encoder "
+        raise UnsupportedStrategy("coded-sgd covers token-only LMs (no patch/encoder "
                          "modalities in the coded worker layout)")
     from ..models import transformer as T
 
@@ -183,7 +184,7 @@ class CodedTrainer:
         self.engine = engine
         self.policy = policy if policy is not None else FastestK(tcfg.wait_k)
         if degrade is not None and degrade.mode == "hold":
-            raise ValueError("coded-sgd supports renormalize/backoff degrade "
+            raise UnsupportedStrategy("coded-sgd supports renormalize/backoff degrade "
                              "only (the decode weights renormalize over the "
                              "active set by construction; see DESIGN.md §15)")
         self.degrade = degrade

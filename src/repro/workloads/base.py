@@ -30,8 +30,8 @@ import numpy as np
 from repro.configs.paper_native import QuadraticProblemConfig
 from repro.obs.trace import span as _obs_span
 from repro.runtime.engine import ClusterEngine, make_delay_model
-from repro.runtime.strategies import (RunResult, get_strategy,
-                                      json_safe_meta)
+from repro.runtime.strategies import (RunResult, UnsupportedStrategy,
+                                      get_strategy, json_safe_meta)
 
 __all__ = [
     "Preset", "Workload", "WorkloadRunResult", "UnsupportedStrategy",
@@ -41,12 +41,6 @@ __all__ = [
 
 
 PRESET_NAMES = ("smoke", "bench", "paper")
-
-
-class UnsupportedStrategy(ValueError):
-    """A strategy that cannot run a given workload — carries the reason, so
-    harnesses (compare, the workloads runner) can skip-with-reason instead
-    of aborting the matrix."""
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,18 +2,6 @@ import numpy as np
 import pytest
 
 
-def pytest_collection_modifyitems(config, items):
-    """Per-test wall-clock ceiling (a hung chaos/fault test must fail, not
-    wedge the suite).  Applied only when pytest-timeout is installed (CI
-    does, via requirements.txt); without the plugin the suite runs
-    unchanged — no warnings, no dependency."""
-    if not config.pluginmanager.hasplugin("timeout"):
-        return
-    for item in items:
-        if item.get_closest_marker("timeout") is None:
-            item.add_marker(pytest.mark.timeout(300))
-
-
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)

@@ -7,10 +7,8 @@ for ANY mask with a survivor per cluster; cyclic repetition for ANY
 1905.05383) trades exactness for an UNBIASED estimate with variance
 bounded by the fixed-degree sampling formula."""
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core.gradient_coding import (make_cyclic, make_frc,  # noqa: E402
                                         make_stochastic)

@@ -4,19 +4,19 @@ and the fault counters the tail estimator now carries."""
 import numpy as np
 import pytest
 
-jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
+import jax
+import jax.numpy as jnp
 
-from repro.core.gradient_coding import (make_code, make_cyclic,  # noqa: E402
+from repro.core.gradient_coding import (make_code, make_cyclic,
                                         make_frc)
-from repro.data.pipeline import GroupBatcher, TokenStream  # noqa: E402
-from repro.models import transformer as T  # noqa: E402
-from repro.obs.sketch import DelayTailEstimator  # noqa: E402
-from repro.optim import adamw_init, cosine_schedule  # noqa: E402
-from repro.runtime import (ClusterEngine, FastestK, get_strategy,  # noqa: E402
+from repro.data.pipeline import GroupBatcher, TokenStream
+from repro.models import transformer as T
+from repro.obs.sketch import DelayTailEstimator
+from repro.optim import adamw_init, cosine_schedule
+from repro.runtime import (ClusterEngine, FastestK, get_strategy,
                            make_delay_model)
-from repro.runtime.faults import FAULT_PRESETS, make_fault_model  # noqa: E402
-from repro.train.coded import (CodedTrainer, TrainProblem,  # noqa: E402
+from repro.runtime.faults import FAULT_PRESETS, make_fault_model
+from repro.train.coded import (CodedTrainer, TrainProblem,
                                TrainerConfig, build_coded_train_step,
                                run_coded_sgd)
 

@@ -1,9 +1,7 @@
 """Property tests for the encoded data-parallel gradient machinery."""
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (hadamard_encoder, gaussian_encoder, identity_encoder,

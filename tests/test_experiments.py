@@ -333,3 +333,81 @@ def test_experiments_cli_plan_only(capsys):
     assert result.outcomes == []
     captured = capsys.readouterr().out
     assert "ExperimentPlan" in captured and "SKIP" in captured
+
+
+# ---------------------------------------------------------------------------
+# failures fail: only UnsupportedStrategy becomes a skip record
+# ---------------------------------------------------------------------------
+
+class _LoweringFails:
+    """Stands in for a kernel the chip's compiler refuses: every entry point
+    raises a plain ValueError, as a Pallas block-shape error does."""
+
+    name = "coded-gd"
+
+    def run(self, *args, **kwargs):
+        raise ValueError("block shape not divisible by (8, 128)")
+
+    run_batched = run_cellbatched = run
+
+
+@pytest.mark.parametrize("extra", [
+    [],
+    ["--trials", "4"],
+    ["--delays", "bimodal,exponential", "--cell-batch"],
+    ["--workloads", "ridge", "--steps", "4"],
+], ids=["single", "trials", "cell-batch", "workload"])
+def test_cell_error_propagates(monkeypatch, tmp_path, extra):
+    from repro.experiments.run import main
+    from repro.runtime import strategies
+    monkeypatch.setitem(strategies._REGISTRY, "coded-gd", _LoweringFails)
+    with pytest.raises(ValueError, match=r"\(8, 128\)"):
+        main(["--strategies", "coded-gd", "--n", str(N), "--p", str(P),
+              "--m", str(M), "--steps", "4", "--delays", "bimodal",
+              "--out", str(tmp_path), *extra])
+
+
+def test_cli_exits_nonzero_on_cell_error(tmp_path):
+    """``python -m repro.experiments.run`` exits non-zero, and writes no
+    records, when a cell raises anything but UnsupportedStrategy."""
+    script = textwrap.dedent(f"""
+        import runpy, sys
+        from repro.runtime import strategies
+
+        class Fails(strategies.Strategy):
+            def run(self, *args, **kwargs):
+                raise ValueError("lowering failed")
+            run_batched = run
+
+        strategies._REGISTRY["coded-gd"] = Fails
+        sys.argv = ["run", "--strategies", "coded-gd", "--n", "64",
+                    "--p", "16", "--m", "4", "--steps", "4",
+                    "--out", {str(tmp_path / "out")!r}]
+        runpy.run_module("repro.experiments.run", run_name="__main__")
+    """)
+    proc = subprocess.run([sys.executable, "-c", script],
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0
+    assert "lowering failed" in proc.stderr
+    assert not (tmp_path / "out" / "experiments.json").exists()
+
+
+@pytest.mark.parametrize("env_dir", [None, "placed-from-outside"])
+def test_compile_cache_placement(tmp_path, env_dir):
+    """The entry points' compile cache: where JAX_COMPILATION_CACHE_DIR
+    says, else the fixed ``.jax_cache/`` at the repository root."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    script = ("import jax; from repro.compile_cache import "
+              "enable_compile_cache; d = enable_compile_cache(); "
+              "print(d); print(jax.config.jax_compilation_cache_dir)")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    returned, configured = proc.stdout.split()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = (str(tmp_path / env_dir) if env_dir is not None
+            else os.path.join(root, ".jax_cache"))
+    assert returned == configured == want

@@ -7,9 +7,7 @@ is graceful (an unbiased mean over surviving clusters, never corruption).
 Skipped when ``hypothesis`` is unavailable (it is not shipped in the
 accelerator image; CI installs it from requirements.txt)."""
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core.gradient_coding import (coded_weights, decode_exact_possible,

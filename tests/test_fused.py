@@ -193,10 +193,14 @@ def test_cellbatched_trials1_keeps_run_schema():
 def test_combine_layout_divisor_over_pad():
     assert combine_layout(2048) == (2048, 0)
     assert combine_layout(37) == (37, 0)          # P <= block: one tile
-    bp, pad = combine_layout(2085)                # 3 * 5 * 139
-    assert pad == 0 and 2085 % bp == 0 and bp >= 128
-    bp, pad = combine_layout(2053)                # prime: must pad
-    assert pad == (-2053) % bp
+    assert combine_layout(6144) == (2048, 0)
+    assert combine_layout(97_536_768) == (1792, 0)   # 100m LM: 14 * 128
+    # no multiple-of-128 divisor (the TPU lane rule): pad to whole blocks,
+    # wasting under 128 columns per block
+    for P_ in (2085, 2053, 6000):                 # 3*5*139, prime, ridge p
+        bp, pad = combine_layout(P_)
+        assert bp % 128 == 0 and (P_ + pad) % bp == 0
+        assert pad < 128 * (P_ + pad) // bp
 
 
 @pytest.mark.parametrize("P_", [37, 2085])

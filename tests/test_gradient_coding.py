@@ -3,9 +3,7 @@ with surviving clusters; graceful degradation otherwise."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (make_frc, coded_weights, decode_exact_possible,

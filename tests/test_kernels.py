@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.fwht import fwht_kernel_call, pick_block_rows

@@ -2,9 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import ARCHS

@@ -201,8 +201,7 @@ def test_stream_worker_blocks_matches_bulk_encode():
 
 
 # ---------------------------------------------------------------------------
-# Fused encode kernel (kernels/encode.py) — no hypothesis dependency, so these
-# live here rather than in test_kernels.py (which importorskips hypothesis).
+# Fused encode kernel (kernels/encode.py)
 # ---------------------------------------------------------------------------
 
 def _srht_oracle(n, p, N, seed):

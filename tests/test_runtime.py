@@ -52,7 +52,10 @@ def test_scan_gd_matches_legacy_loop(spec, schedule):
     for t in range(schedule.steps):
         w = gd_step(prob, w, jnp.asarray(schedule.masks[t]), step, h="l2")
         tr.append(float(original_objective(prob, w, h="l2")))
-    np.testing.assert_allclose(np.asarray(tr_scan), np.asarray(tr), atol=1e-5)
+    # float32 scan trace vs the loop's Python-float trace of objectives
+    # near 30: a rounding difference, so the tolerance is relative
+    np.testing.assert_allclose(np.asarray(tr_scan), np.asarray(tr),
+                               rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(w_scan), np.asarray(w), atol=1e-5)
 
 
@@ -66,7 +69,10 @@ def test_scan_prox_matches_legacy_loop(spec, schedule):
     for t in range(schedule.steps):
         w = prox_step(prob, w, jnp.asarray(schedule.masks[t]), step)
         tr.append(float(original_objective(prob, w, h="l1")))
-    np.testing.assert_allclose(np.asarray(tr_scan), np.asarray(tr), atol=1e-5)
+    # float32 scan trace vs the loop's Python-float trace of objectives
+    # near 30: a rounding difference, so the tolerance is relative
+    np.testing.assert_allclose(np.asarray(tr_scan), np.asarray(tr),
+                               rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(w_scan), np.asarray(w), atol=1e-5)
 
 

@@ -10,17 +10,8 @@ from repro.models import transformer as T
 from repro.sharding import spec_for_shape, make_specs
 
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across JAX versions: <=0.4.x takes ((name, size), ...)
-    pairs; newer releases take (sizes, names)."""
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(sizes), tuple(names))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_spec_divisible_dims_sharded():
@@ -70,10 +61,7 @@ def test_analyze_hlo_scan_multiplier():
         return y
     c = jax.jit(scanned).lower(
         jax.ShapeDtypeStruct((128, 128), jnp.float32)).compile()
-    ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax<=0.4.x: one dict per partition
-        ca = ca[0]
-    naive = ca["flops"]
+    naive = c.cost_analysis()["flops"]
     aware = analyze_hlo(c.as_text())["flops"]
     single = 2 * 128 ** 3
     assert naive < 1.01 * single      # XLA counts the body once
