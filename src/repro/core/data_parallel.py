@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span as _obs_span
+
 from .encoding import LinearEncoder
 
 __all__ = [
@@ -63,14 +65,20 @@ def make_encoded_problem(X: np.ndarray, y: np.ndarray, enc: LinearEncoder,
     (n, p+1) pass, since the operator acts columnwise.
     """
     enc = enc.with_workers(m)
-    Xy = np.concatenate([np.asarray(X, np.float64),
-                         np.asarray(y, np.float64)[:, None]], axis=1)
-    SXy = np.stack([np.asarray(b, np.float64)
-                    for b in enc.encode_partitioned(Xy)])  # (m, r, p+1)
-    return EncodedProblem(
-        SX=jnp.asarray(SXy[..., :-1], dtype), Sy=jnp.asarray(SXy[..., -1], dtype),
-        X=jnp.asarray(X, dtype), y=jnp.asarray(y, dtype),
-        lam=float(lam), beta=float(enc.beta), n=X.shape[0])
+    with _obs_span("encode:prepare"):
+        Xy = np.concatenate([np.asarray(X, np.float64),
+                             np.asarray(y, np.float64)[:, None]], axis=1)
+    with _obs_span("encode:transform"):
+        blocks = jax.block_until_ready(enc.encode_partitioned(Xy))
+    with _obs_span("encode:readback"):
+        SXy = np.stack([np.asarray(b, np.float64)
+                        for b in blocks])                  # (m, r, p+1)
+    with _obs_span("encode:upload"):
+        SX = jnp.asarray(SXy[..., :-1], dtype)
+        Sy = jnp.asarray(SXy[..., -1], dtype)
+        Xd, yd = jnp.asarray(X, dtype), jnp.asarray(y, dtype)
+    return EncodedProblem(SX=SX, Sy=Sy, X=Xd, y=yd, lam=float(lam),
+                          beta=float(enc.beta), n=X.shape[0])
 
 
 def original_objective(prob: EncodedProblem, w: jax.Array,

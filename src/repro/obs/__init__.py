@@ -22,11 +22,12 @@ The observability substrate under every execution layer (DESIGN.md §11):
     stored runs (or a bench json vs its committed baseline) cell-by-cell
     and exits non-zero on wall-clock/convergence regressions;
   * ``python -m repro.obs.report`` — text straggler-timeline /
-    phase-breakdown reports from a saved trace, plus a self-contained
-    ``--html`` export.
+    phase-breakdown reports from a saved trace.
 
-Design rule: with no active recorder every hook is a single ``is None``
-check — observability off is a zero-cost no-op path.
+Design rule: with no active recorder every recorder hook is a single
+``is None`` check, and a span is a ``jax.profiler.TraceAnnotation``, which
+does nothing while no profiler session runs; nothing in ``obs`` blocks on
+the device.
 """
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       async_metrics, cell_summary, clamp_async_event,

@@ -10,8 +10,8 @@ The comparison layer over :mod:`repro.obs.runstore` (DESIGN.md §13):
   * :func:`diff_bench` — align two ``BENCH_*.json`` trees by path and
     compare every time-like leaf (``*_s``, ``us_*``, ``seconds*``);
   * :class:`DiffReport` — the result: per-cell :class:`CellDelta` rows,
-    regression list, exit code (0 clean / 1 regression), text and HTML
-    renderings.  ``python -m repro.obs.diff`` is the CLI front-end the
+    regression list, exit code (0 clean / 1 regression), text
+    rendering.  ``python -m repro.obs.diff`` is the CLI front-end the
     CI bench-regression gate calls.
 
 Gating semantics: a cell regresses when its wall-clock ratio
@@ -24,12 +24,11 @@ reported but never gated (metric direction is workload-specific).
 from __future__ import annotations
 
 import dataclasses
-import html as _html
 
 __all__ = [
     "CELL_KEY_FIELDS", "cell_key", "summarize_records", "Thresholds",
     "CellDelta", "DiffReport", "diff_manifests", "diff_bench",
-    "flatten_bench", "render_html_page",
+    "flatten_bench",
 ]
 
 
@@ -169,36 +168,6 @@ class DiffReport:
             out.append(f"RESULT: OK ({len(self.deltas)} compared, "
                        f"0 regressions)")
         return "\n".join(out)
-
-    def render_html_section(self) -> str:
-        rows = []
-        for d in self.deltas:
-            cls = {"regression": "bad", "improved": "good"}.get(d.status,
-                                                                "")
-            ratio = f"{d.ratio:.2f}x" if d.ratio is not None else "–"
-            rel = (f"{d.objective_rel:+.1%}"
-                   if d.objective_rel is not None else "–")
-            wa = f"{d.wallclock_a:.4g}" if d.wallclock_a is not None else "–"
-            wb = f"{d.wallclock_b:.4g}" if d.wallclock_b is not None else "–"
-            rows.append(
-                f"<tr class='{cls}'><td>{_html.escape(d.label)}</td>"
-                f"<td>{wa}</td><td>{wb}</td><td>{ratio}</td><td>{rel}</td>"
-                f"<td>{d.status}"
-                + (f" <small>{_html.escape('; '.join(d.reasons))}</small>"
-                   if d.reasons else "")
-                + "</td></tr>")
-        verdict = (f"<p class='bad'><b>REGRESSION</b>: "
-                   f"{len(self.regressions)} cell(s)</p>"
-                   if self.regressions else
-                   "<p class='good'><b>OK</b>: no regressions</p>")
-        notes = "".join(f"<p><small>{_html.escape(n)}</small></p>"
-                        for n in self.notes)
-        return (
-            f"<h2>{self.kind} diff: {_html.escape(self.a_label)} &rarr; "
-            f"{_html.escape(self.b_label)}</h2>{notes}{verdict}"
-            "<table><tr><th>cell</th><th>a</th><th>b</th><th>ratio</th>"
-            "<th>objective &Delta;</th><th>status</th></tr>"
-            + "".join(rows) + "</table>")
 
 
 # ---------------------------------------------------------------------------
@@ -351,37 +320,3 @@ def diff_bench(a, b, *, thresholds: Thresholds | None = None,
     if not report.deltas:
         report.notes.append("no overlapping time-like leaves")
     return report
-
-
-# ---------------------------------------------------------------------------
-# Shared HTML page scaffold
-# ---------------------------------------------------------------------------
-
-_CSS = """
-body { font: 14px/1.45 system-ui, sans-serif; margin: 2em auto;
-       max-width: 70em; color: #1a1a2e; padding: 0 1em; }
-h1, h2 { font-weight: 600; }
-table { border-collapse: collapse; margin: 1em 0; width: 100%; }
-th, td { border: 1px solid #d8d8e0; padding: .3em .6em;
-         text-align: left; font-variant-numeric: tabular-nums; }
-th { background: #f2f2f7; }
-tr.bad td { background: #fdecec; }
-tr.good td { background: #ecf8ef; }
-.bad { color: #b3261e; } .good { color: #1e7d32; }
-pre.lanes { font: 12px/1.2 ui-monospace, monospace; background: #f7f7fa;
-            padding: .8em; overflow-x: auto; }
-.bar { display: inline-block; height: .75em; background: #5b72d8;
-       vertical-align: baseline; }
-.bar.miss { background: #d86a5b; }
-small { color: #666; }
-"""
-
-
-def render_html_page(title: str, sections: list[str]) -> str:
-    """One self-contained HTML document (inline CSS, no external
-    assets) from pre-rendered body sections."""
-    body = "\n".join(sections)
-    return (f"<!doctype html><html><head><meta charset='utf-8'>"
-            f"<title>{_html.escape(title)}</title>"
-            f"<style>{_CSS}</style></head><body>"
-            f"<h1>{_html.escape(title)}</h1>\n{body}</body></html>")

@@ -15,8 +15,8 @@ output directly against the committed baseline.
 Exit codes: ``0`` no regression, ``1`` at least one gated leaf/cell
 regressed, ``2`` usage / resolution error.  Thresholds are configurable
 (``--threshold`` wall-clock ratio, ``--metric-threshold`` relative
-objective worsening); ``--json`` and ``--html`` write machine- and
-human-readable reports alongside the text summary.
+objective worsening); ``--json`` writes a machine-readable report
+alongside the text summary.
 """
 from __future__ import annotations
 
@@ -25,8 +25,7 @@ import json
 import os
 import sys
 
-from .analyze import (Thresholds, diff_bench, diff_manifests,
-                      render_html_page)
+from .analyze import Thresholds, diff_bench, diff_manifests
 from .runstore import DEFAULT_ROOT, ENV_VAR, RunStore
 
 __all__ = ["main"]
@@ -58,8 +57,6 @@ def _build_parser() -> argparse.ArgumentParser:
                          "noise never flags (default 1e-3)")
     ap.add_argument("--json", metavar="PATH", dest="json_out",
                     help="write the full report as JSON")
-    ap.add_argument("--html", metavar="PATH", dest="html_out",
-                    help="write a self-contained HTML report")
     return ap
 
 
@@ -133,13 +130,6 @@ def main(argv=None) -> int:
         _ensure_parent(args.json_out)
         with open(args.json_out, "w") as f:
             json.dump(report.to_dict(), f, indent=1)
-    if args.html_out:
-        page = render_html_page(
-            f"repro diff: {report.a_label} vs {report.b_label}",
-            [report.render_html_section()])
-        _ensure_parent(args.html_out)
-        with open(args.html_out, "w") as f:
-            f.write(page)
     return report.exit_code
 
 

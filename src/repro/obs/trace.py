@@ -12,11 +12,18 @@ A :class:`TraceRecorder` captures two clock domains into one event stream:
     ``sample-schedule``, ``solve``, ``chunk``, ...), relative to recorder
     creation.
 
+:func:`span` is the program's one span mechanism.  Every span is also a
+``jax.profiler.TraceAnnotation``, so it lands on the host plane of any
+``jax.profiler`` trace, on the device trace's clock, whether or not a
+recorder is active; with no profiler session the annotation is one
+enabled-check.  A span never blocks on the device: device execute time is
+the profiler trace's to show.
+
 Recording is cheap by construction: the engine hands the recorder the
 ``Schedule`` / ``AsyncTrace`` it already built and the recorder stores a
 *reference* (one list append); expansion into per-worker events happens only
-at export/inspection time.  With no active recorder every hook is a single
-``is None`` check — the disabled path does no work at all.
+at export/inspection time.  With no active recorder every recorder hook is
+a single ``is None`` check.
 
 Exports: JSONL (``to_jsonl`` / ``TraceRecorder.load`` round-trip) and
 Chrome/Perfetto ``trace_event`` JSON (``to_perfetto``) that opens directly
@@ -32,6 +39,7 @@ import time
 from typing import Any, Iterator
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["TraceEvent", "TraceRecorder", "current_recorder", "span"]
 
@@ -97,11 +105,12 @@ def current_recorder() -> "TraceRecorder | None":
 
 
 def span(name: str, **args):
-    """Context manager recording a host-clock span on the active recorder;
-    a shared no-op when tracing is disabled."""
+    """Context manager for the program phase ``name``: a profiler
+    annotation, also recorded as a host-clock span on the active recorder
+    if there is one."""
     rec = _ACTIVE
     if rec is None:
-        return contextlib.nullcontext()
+        return TraceAnnotation(name, **args)
     return rec.span(name, **args)
 
 
@@ -158,7 +167,8 @@ class TraceRecorder:
     def span(self, name: str, **args):
         t0 = self._now()
         try:
-            yield self
+            with TraceAnnotation(name, **args):
+                yield self
         finally:
             self._append(TraceEvent(kind="span", name=name, ts=t0,
                                     dur=self._now() - t0, lane="host",

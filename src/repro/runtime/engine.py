@@ -523,16 +523,19 @@ class ClusterEngine:
         """
         if trials < 1:
             raise ValueError("trials must be >= 1")
-        scheds = tuple(self.sample_schedule(steps, policy, realization=r,
-                                            degrade=degrade)
-                       for r in range(trials))
-        return ScheduleBatch(
-            m=self.m,
-            masks=np.stack([s.masks for s in scheds]),
-            times=np.stack([s.times for s in scheds]),
-            schedules=scheds,
-            failed=(np.stack([s.failed for s in scheds])
-                    if scheds[0].failed is not None else None))
+        with _obs_span("sample-schedules", steps=steps, trials=trials,
+                       m=self.m):
+            scheds = tuple(self.sample_schedule(steps, policy,
+                                                realization=r,
+                                                degrade=degrade)
+                           for r in range(trials))
+            return ScheduleBatch(
+                m=self.m,
+                masks=np.stack([s.masks for s in scheds]),
+                times=np.stack([s.times for s in scheds]),
+                schedules=scheds,
+                failed=(np.stack([s.failed for s in scheds])
+                        if scheds[0].failed is not None else None))
 
     # -- asynchronous (per-arrival) mode --------------------------------
 

@@ -38,7 +38,7 @@ from repro.core.data_parallel import (EncodedProblem, masked_gradient,
                                       original_objective, prox_l1)
 from repro.core.model_parallel import LiftedProblem
 from repro.kernels.fused_step import fused_enabled, fused_masked_gradient
-from repro.obs.trace import current_recorder as _obs_recorder
+from repro.obs.trace import span as _obs_span
 
 __all__ = [
     "scan_gd", "scan_prox", "scan_bcd", "scan_async",
@@ -50,17 +50,12 @@ __all__ = [
 
 
 def _traced_call(name: str, fn, *args, **kw):
-    """Dispatch a runner; under an active obs ``TraceRecorder`` the call is
-    wrapped in a host-clock span and blocked on every output leaf so the
-    span covers the real device execute time.  With tracing off this is one
-    module-global check and the dispatch stays asynchronous."""
-    rec = _obs_recorder()
-    if rec is None:
+    """Dispatch a runner inside the program span ``name``.  The span covers
+    the dispatch only and never blocks, recorder or not: the device's
+    execute time is the profiler trace's to show, and a recorded run takes
+    the path of an unrecorded one."""
+    with _obs_span(name):
         return fn(*args, **kw)
-    with rec.span(name):
-        out = fn(*args, **kw)
-        jax.block_until_ready(out)
-    return out
 
 
 # ---------------------------------------------------------------------------

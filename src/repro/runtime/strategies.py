@@ -398,9 +398,10 @@ class _SyncGradientStrategy(Strategy):
         else:
             w, tr = scan_gd(prob, masks, step_size, w0, h=spec.h,
                             degrade=degrade)
+        with _obs_span("solve:readback"):
+            w, tr = np.asarray(w), np.asarray(tr)
         return RunResult(
-            strategy=self.name, times=sched.times, objective=np.asarray(tr),
-            w=np.asarray(w),
+            strategy=self.name, times=sched.times, objective=tr, w=w,
             meta={"encoder": enc.name, "beta": enc.beta,
                   "policy": type(policy).__name__, "step_size": step_size,
                   "mean_active": float(sched.masks.sum(1).mean()),
@@ -453,11 +454,12 @@ class _SyncGradientStrategy(Strategy):
         else:
             w, tr = batched_scan_gd(prob, masks, step_size, w0, h=spec.h,
                                     eval_every=stride_every, degrade=degrade)
+        with _obs_span("solve:readback"):
+            w, tr = np.asarray(w), np.asarray(tr)
         return TrialsResult(
             strategy=self.name,
             times=batch.times[:, stride_every - 1::stride_every],
-            objective=np.asarray(tr), w=np.asarray(w),
-            meta=meta, schedules=batch)
+            objective=tr, w=w, meta=meta, schedules=batch)
 
     def run_cellbatched(self, spec, engines, *, steps=200, trials=1,
                         eval_every=1, cfgs=None):
@@ -513,7 +515,8 @@ class _SyncGradientStrategy(Strategy):
         else:
             w, tr = batched_scan_gd(prob, masks, step_vec, w0, h=spec.h,
                                     eval_every=stride_every, degrade=degrade)
-        w, tr = np.asarray(w), np.asarray(tr)
+        with _obs_span("solve:readback"):
+            w, tr = np.asarray(w), np.asarray(tr)
         results = []
         for ci in range(C):
             sl = slice(ci * trials, (ci + 1) * trials)

@@ -37,9 +37,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ArchConfig
-from ..core.gradient_coding import GradientCode, make_code
+from ..core.gradient_coding import FRCode, GradientCode, make_code
 from ..data.pipeline import GroupBatcher, TokenStream
 from ..kernels.coded_reduce import coded_combine_call
+from ..obs.metrics import MetricsRegistry
 from ..obs.timing import CompileWatch, block
 from ..obs.trace import span as _obs_span
 from ..optim import adamw_init, adamw_update, cosine_schedule
@@ -193,6 +194,9 @@ class CodedTrainer:
             cfg, lr_fn, rows_per_group=tcfg.rows_per_worker,
             num_groups=self.code.num_groups))
         self.last_schedule = None
+        # ``steps`` run and ``host_syncs``: the points of a step at which
+        # the host waits on the device
+        self.metrics = MetricsRegistry()
 
     def init_state(self, key=None):
         from ..models import transformer as T
@@ -209,37 +213,56 @@ class CodedTrainer:
                                             degrade=self.degrade)
         self.last_schedule = sched
         history = []
+        steps, syncs = (self.metrics.counter("steps"),
+                        self.metrics.counter("host_syncs"))
         with _obs_span("train:coded", code=self.code.codename,
                        steps=tc.steps, m=tc.m_workers):
             for t in range(tc.steps):
-                code_t = self.code.at_step(t)
-                tokens, labels, coeff = self.batcher.next_batch(code_t)
-                mask = np.asarray(sched.masks[t])
-                decode = code_t.decode_weights(mask)
-                with CompileWatch() as cw:
-                    params, opt, metrics = block(self._step(
-                        params, opt, jnp.asarray(tokens),
-                        jnp.asarray(labels), jnp.asarray(coeff),
-                        jnp.asarray(decode)))
-                rec = {"step": t, "loss": float(metrics["loss"]),
-                       "grad_norm": float(metrics["grad_norm"]),
-                       "sim_time_s": float(sched.times[t]),
-                       "active": int((mask > 0).sum()),
-                       "exact": bool(code_t.decode_exact_possible(mask)),
-                       "host_s": cw.total_s, "compile_s": cw.compile_s,
-                       "execute_s": cw.execute_s, "compiles": cw.compiles}
-                history.append(rec)
-                if callback:
-                    callback(rec)
-                if tc.log_every and t % tc.log_every == 0:
-                    print(f"step {t:5d} loss {rec['loss']:.4f} "
-                          f"gnorm {rec['grad_norm']:.3f} "
-                          f"active {rec['active']}/{tc.m_workers} "
-                          f"simtime {rec['sim_time_s']:.1f}s", flush=True)
-                if (tc.checkpoint_dir and tc.checkpoint_every
-                        and (t + 1) % tc.checkpoint_every == 0):
-                    from ..checkpoint import save
-                    save(tc.checkpoint_dir, t + 1, (params, opt))
+                with _obs_span("train:step", step=t):
+                    with _obs_span("train:batch"):
+                        code_t = self.code.at_step(t)
+                        tokens, labels, coeff = self.batcher.next_batch(
+                            code_t)
+                    with _obs_span("train:decode"):
+                        mask = np.asarray(sched.masks[t])
+                        decode = code_t.decode_weights(mask)
+                    with CompileWatch() as cw:
+                        with _obs_span("train:dispatch"):
+                            out = self._step(
+                                params, opt, jnp.asarray(tokens),
+                                jnp.asarray(labels), jnp.asarray(coeff),
+                                jnp.asarray(decode))
+                        with _obs_span("train:wait"):
+                            params, opt, metrics = block(out)
+                    with _obs_span("train:readback"):
+                        rec = {"step": t, "loss": float(metrics["loss"]),
+                               "grad_norm": float(metrics["grad_norm"]),
+                               "sim_time_s": float(sched.times[t]),
+                               "active": int((mask > 0).sum()),
+                               "exact": bool(
+                                   code_t.decode_exact_possible(mask)),
+                               "host_s": cw.total_s,
+                               "compile_s": cw.compile_s,
+                               "execute_s": cw.execute_s,
+                               "compiles": cw.compiles}
+                        history.append(rec)
+                    # the host waits on the device at block and at the two
+                    # float reads; FRC decode weights are also read back
+                    steps.inc()
+                    syncs.inc(3 + isinstance(code_t, FRCode))
+                    with _obs_span("train:callback"):
+                        if callback:
+                            callback(rec)
+                    if tc.log_every and t % tc.log_every == 0:
+                        print(f"step {t:5d} loss {rec['loss']:.4f} "
+                              f"gnorm {rec['grad_norm']:.3f} "
+                              f"active {rec['active']}/{tc.m_workers} "
+                              f"simtime {rec['sim_time_s']:.1f}s",
+                              flush=True)
+                    if (tc.checkpoint_dir and tc.checkpoint_every
+                            and (t + 1) % tc.checkpoint_every == 0):
+                        from ..checkpoint import save
+                        save(tc.checkpoint_dir, t + 1, (params, opt))
         return params, opt, history
 
 

@@ -3,12 +3,15 @@ deterministic event streams, per-realization lanes, metrics vs a
 hand-computed schedule, async staleness clamping at the trace boundary,
 the CompileWatch compile/execute split, the disabled-path no-op guarantee
 (structure + overhead guard), the report CLI and the experiments wiring
-(ObsAxis gating, --trace/--metrics-out end-to-end)."""
+(ObsAxis gating, --trace/--metrics-out end-to-end), the program's phase
+spans on the profiler's clock, and runners that never block."""
 import contextlib
 import csv
+import glob
 import json
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -47,7 +50,7 @@ def _hand_schedule():
 
 def test_disabled_path_is_noop():
     assert current_recorder() is None
-    assert isinstance(span("x", a=1), contextlib.nullcontext)
+    assert isinstance(span("x", a=1), jax.profiler.TraceAnnotation)
     rec = TraceRecorder()
     _engine().sample_schedule(T, FastestK(K))
     assert rec.events() == []          # nothing recorded while inactive
@@ -374,3 +377,105 @@ def test_workload_matrix_obs_kwarg():
     assert "obs" in records[0] and "compile_s" in records[0]
     plain = run_workload_matrix(["ridge"], ["uncoded"], steps=6, trials=2)
     assert "obs" not in plain[0]
+
+
+# ---------------------------------------------------------------------------
+# program phase spans (profiler annotations; runners never block)
+# ---------------------------------------------------------------------------
+
+def _children(spans, parent):
+    """Spans strictly inside ``parent`` on the host clock, in order."""
+    return [e for e in spans if e is not parent
+            and parent.ts <= e.ts and e.ts + e.dur <= parent.ts + parent.dur]
+
+
+def test_run_batched_records_encode_phases_and_readback():
+    from repro.runtime import get_strategy
+    from repro.runtime.strategies import ProblemSpec
+    spec = ProblemSpec.synthetic(64, 16)
+    rec = TraceRecorder()
+    with rec.activate():
+        get_strategy("coded-gd").run_batched(
+            spec, _engine(), steps=6, trials=3, k=K,
+            encoder="fast-hadamard")
+    spans = rec.spans()
+    names = [e.name for e in spans]
+    (encode,) = [e for e in spans if e.name == "encode"]
+    assert [e.name for e in _children(spans, encode)] == [
+        "encode:prepare", "encode:transform", "encode:readback",
+        "encode:upload"]
+    (draw,) = [e for e in spans if e.name == "sample-schedules"]
+    assert [e.name for e in _children(spans, draw)] == \
+        ["sample-schedule"] * 3
+    assert names.count("solve:readback") == 1
+    assert names.index("solve:readback") > names.index("sample-schedules")
+
+
+def test_coded_trainer_records_step_phases_and_syncs():
+    from repro.train.coded import CodedTrainer, TrainProblem, TrainerConfig
+    cfg = TrainProblem(seq_len=16, vocab=64).build_cfg()
+    tcfg = TrainerConfig(m_workers=M, beta=2, wait_k=K, rows_per_worker=1,
+                         seq_len=16, steps=2, lr=1e-3, warmup=1,
+                         log_every=0)
+    trainer = CodedTrainer(cfg, tcfg, _engine())
+    seen = []
+    rec = TraceRecorder()
+    with rec.activate():
+        trainer.run(callback=seen.append)
+    spans = rec.spans()
+    steps = [e for e in spans if e.name == "train:step"]
+    assert len(steps) == 2 and len(seen) == 2
+    for step in steps:
+        assert [e.name for e in _children(spans, step)] == [
+            "train:batch", "train:decode", "train:dispatch", "train:wait",
+            "train:readback", "train:callback"]
+    (outer,) = [e for e in spans if e.name == "train:coded"]
+    assert all(e in _children(spans, outer) for e in steps)
+    assert trainer.metrics.summary() == {"host_syncs": 8, "steps": 2}
+
+
+@pytest.mark.parametrize("runner", ["scan_gd", "scan_prox",
+                                    "batched_scan_gd", "batched_scan_prox"])
+def test_runners_never_block_under_a_recorder(runner, monkeypatch):
+    import jax.numpy as jnp
+    from repro.core.data_parallel import make_encoded_problem
+    from repro.core.encoding import make_encoder
+    from repro.runtime import runners
+    spec_X = np.random.default_rng(0).standard_normal((32, 8))
+    prob = make_encoded_problem(spec_X, spec_X[:, 0], make_encoder(
+        "hadamard", 32, beta=2.0, seed=0), M, lam=0.1)
+    batched = runner.startswith("batched")
+    shape = (3, T, M) if batched else (T, M)
+    masks = jnp.asarray(np.ones(shape, np.float32))
+    w0 = jnp.zeros((3, 8) if batched else (8,), jnp.float32)
+    calls = []
+    orig = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(1) or orig(x))
+    rec = TraceRecorder()
+    with rec.activate():
+        w, tr = getattr(runners, runner)(prob, masks, 0.1, w0)
+    assert calls == []
+    (ev,) = rec.spans()
+    assert ev.name.startswith("runner:")
+    assert np.isfinite(np.asarray(tr)).all()
+
+
+def _host_events(logdir):
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb")
+    return [ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events]
+
+
+@pytest.mark.parametrize("recorded", [False, True])
+def test_span_lands_on_the_profilers_host_plane(recorded, tmp_path):
+    rec = TraceRecorder()
+    with jax.profiler.trace(str(tmp_path)):
+        with rec.activate() if recorded else contextlib.nullcontext():
+            with span("phase:x", n=3):
+                pass
+    assert "phase:x" in _host_events(tmp_path)
+    assert [e.name for e in rec.spans()] == (["phase:x"] if recorded
+                                             else [])

@@ -238,14 +238,10 @@ def test_diff_latest_refs_and_reports(tmp_path, monkeypatch, capsys):
     store, a, b = _two_runs(tmp_path, slowdown=2.0)
     monkeypatch.setenv("REPRO_RUNSTORE", store.root)
     js = tmp_path / "d.json"
-    html = tmp_path / "d.html"
-    rc = diff_main(["latest~1", "latest", "--json", str(js),
-                    "--html", str(html)])
+    rc = diff_main(["latest~1", "latest", "--json", str(js)])
     assert rc == 1
     rep = json.loads(js.read_text())
     assert rep["exit_code"] == 1 and rep["regressions"] == 1
-    page = html.read_text()
-    assert page.startswith("<!doctype html>") and "REGRESSION" in page
 
 
 def test_diff_unknown_ref_exits_2(tmp_path, capsys):
@@ -276,31 +272,3 @@ def test_bench_meta_stamp():
     from benchmarks.common import bench_meta
     meta = bench_meta()
     assert set(meta) >= {"git_sha", "timestamp", "backend", "jax_version"}
-
-
-# ---------------------------------------------------------------------------
-# html report
-# ---------------------------------------------------------------------------
-
-
-def test_report_html_export(tmp_path):
-    from repro.obs import TraceRecorder
-    from repro.obs.report import main as report_main
-    from repro.runtime import ClusterEngine, FastestK, make_delay_model
-    rec = TraceRecorder()
-    with rec.activate(), rec.cell("codedxbimodal"):
-        with rec.span("solve"):
-            pass
-        eng = ClusterEngine(make_delay_model("bimodal"), 4)
-        eng.sample_schedule(6, FastestK(3))
-        eng.sample_async(8, 2)
-    tr = tmp_path / "t.jsonl"
-    rec.to_jsonl(str(tr))
-    html = tmp_path / "r.html"
-    report_main([str(tr), "--html", str(html)])
-    page = html.read_text()
-    assert page.startswith("<!doctype html>")
-    assert "phase breakdown" in page
-    assert "straggler timeline" in page and "codedxbimodal" in page
-    assert "<pre class='lanes'>" in page
-    assert "staleness" in page
