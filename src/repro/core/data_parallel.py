@@ -53,30 +53,58 @@ class EncodedProblem:
         return self.SX.shape[0]
 
 
+@partial(jax.jit, static_argnames=("m", "dtype"))
+def _worker_stack(SXy: jax.Array, m: int, dtype) -> tuple:
+    """(m, r, p) SX and (m, r) Sy from the encoded [X|y] (m r, p+1): worker
+    i owns rows [i r, (i+1) r), the blocks ``encode_partitioned`` cuts."""
+    blocks = SXy.reshape(m, -1, SXy.shape[-1])
+    return blocks[..., :-1].astype(dtype), blocks[..., -1].astype(dtype)
+
+
 def make_encoded_problem(X: np.ndarray, y: np.ndarray, enc: LinearEncoder,
                          m: int, lam: float = 0.0,
                          dtype=jnp.float32) -> EncodedProblem:
     """Build the worker-stacked encoded problem from any encoding operator.
 
-    Per-worker blocks are built via ``enc.encode_partitioned`` (by default
-    one lazy ``worker_block`` per worker) — S is never materialized and
-    structured encoders only touch the input coordinates each worker's
-    rows depend on (``input_slice``).  X and y are encoded jointly as one
-    (n, p+1) pass, since the operator acts columnwise.
+    X and y are encoded jointly as one (n, p+1) pass, since the operator
+    acts columnwise.  The route follows ``enc.on_device``:
+
+    * Device encoders (``fast-hadamard``) encode in float32 on the device.
+      X and y are uploaded once in ``dtype`` (they become ``prob.X`` and
+      ``prob.y``), [X|y] is joined and encoded there, and one jitted
+      reshape cuts SX and Sy.  Nothing returns to the host and nothing
+      blocks: the solve's scan is the next consumer.  With float32 storage
+      this is bit for bit the host route's problem, whose f32 -> f64 -> f32
+      round trip is exact.
+    * Host encoders (dense, block-diagonal) compute ``S @ [X|y]`` in numpy
+      float64 and round to ``dtype`` once at the upload; encoding rounded
+      inputs would change their result.  Per-worker blocks come from
+      ``enc.encode_partitioned`` (one lazy ``worker_block`` per worker by
+      default), so S is never materialized and structured encoders only
+      touch the input coordinates each worker's rows depend on
+      (``input_slice``).
     """
     enc = enc.with_workers(m)
-    with _obs_span("encode:prepare"):
-        Xy = np.concatenate([np.asarray(X, np.float64),
-                             np.asarray(y, np.float64)[:, None]], axis=1)
-    with _obs_span("encode:transform"):
-        blocks = jax.block_until_ready(enc.encode_partitioned(Xy))
-    with _obs_span("encode:readback"):
-        SXy = np.stack([np.asarray(b, np.float64)
-                        for b in blocks])                  # (m, r, p+1)
-    with _obs_span("encode:upload"):
-        SX = jnp.asarray(SXy[..., :-1], dtype)
-        Sy = jnp.asarray(SXy[..., -1], dtype)
-        Xd, yd = jnp.asarray(X, dtype), jnp.asarray(y, dtype)
+    if enc.on_device:
+        with _obs_span("encode:upload"):
+            Xd, yd = jnp.asarray(X, dtype), jnp.asarray(y, dtype)
+        with _obs_span("encode:transform"):
+            SX, Sy = _worker_stack(
+                enc.encode(jnp.concatenate([Xd, yd[:, None]], axis=1)),
+                m=m, dtype=dtype)
+    else:
+        with _obs_span("encode:prepare"):
+            Xy = np.concatenate([np.asarray(X, np.float64),
+                                 np.asarray(y, np.float64)[:, None]], axis=1)
+        with _obs_span("encode:transform"):
+            blocks = jax.block_until_ready(enc.encode_partitioned(Xy))
+        with _obs_span("encode:readback"):
+            SXy = np.stack([np.asarray(b, np.float64)
+                            for b in blocks])                  # (m, r, p+1)
+        with _obs_span("encode:upload"):
+            SX = jnp.asarray(SXy[..., :-1], dtype)
+            Sy = jnp.asarray(SXy[..., -1], dtype)
+            Xd, yd = jnp.asarray(X, dtype), jnp.asarray(y, dtype)
     return EncodedProblem(SX=SX, Sy=Sy, X=Xd, y=yd, lam=float(lam),
                           beta=float(enc.beta), n=X.shape[0])
 

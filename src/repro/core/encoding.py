@@ -79,6 +79,9 @@ class LinearEncoder:
     # subclasses does not absorb them as implicit field defaults.
     m = None                 # worker count once partitioned
     _pad = 0                 # trailing zero rows added by with_workers
+    # True where ``encode`` computes on the device in float32:
+    # ``make_encoded_problem`` then keeps the encoded problem there
+    on_device = False
 
     # -- shape/metadata (subclass responsibility) ---------------------------
     @property

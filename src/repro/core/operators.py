@@ -57,6 +57,7 @@ class FastHadamardEncoder(LinearEncoder):
 
     name = "fast-hadamard"
     tight = True
+    on_device = True
 
     def __init__(self, n: int, beta: float = 2.0, seed: int = 0):
         self._n = int(n)
@@ -139,6 +140,11 @@ class FastHadamardEncoder(LinearEncoder):
         per worker, with a fresh jit specialization per row window).
         ``worker_block`` stays the entry point for streaming / distributed
         per-worker encode, where blocks are NOT built on one host.
+
+        ``make_encoded_problem`` does not call this: the encoder is
+        ``on_device``, so it runs ``encode`` on the uploaded float32 [X|y]
+        and cuts the same blocks by one reshape on the device.  The lifted
+        problem (``core.model_parallel``) still takes these blocks.
         """
         m = self._require_workers()
         out = self.encode(X)                 # pad rows already appended

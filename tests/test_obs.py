@@ -389,21 +389,25 @@ def _children(spans, parent):
             and parent.ts <= e.ts and e.ts + e.dur <= parent.ts + parent.dur]
 
 
-def test_run_batched_records_encode_phases_and_readback():
+@pytest.mark.parametrize("encoder,phases", [
+    # a device encoder's problem never leaves the device
+    ("fast-hadamard", ["encode:upload", "encode:transform"]),
+    # a host encoder's blocks are built in f64 on the host and uploaded
+    ("hadamard", ["encode:prepare", "encode:transform", "encode:readback",
+                  "encode:upload"]),
+])
+def test_run_batched_records_encode_phases_and_readback(encoder, phases):
     from repro.runtime import get_strategy
     from repro.runtime.strategies import ProblemSpec
     spec = ProblemSpec.synthetic(64, 16)
     rec = TraceRecorder()
     with rec.activate():
         get_strategy("coded-gd").run_batched(
-            spec, _engine(), steps=6, trials=3, k=K,
-            encoder="fast-hadamard")
+            spec, _engine(), steps=6, trials=3, k=K, encoder=encoder)
     spans = rec.spans()
     names = [e.name for e in spans]
     (encode,) = [e for e in spans if e.name == "encode"]
-    assert [e.name for e in _children(spans, encode)] == [
-        "encode:prepare", "encode:transform", "encode:readback",
-        "encode:upload"]
+    assert [e.name for e in _children(spans, encode)] == phases
     (draw,) = [e for e in spans if e.name == "sample-schedules"]
     assert [e.name for e in _children(spans, draw)] == \
         ["sample-schedule"] * 3
