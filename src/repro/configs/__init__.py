@@ -1,4 +1,4 @@
-"""Config registry: the 10 assigned architectures + paper-native problems.
+"""Config registry: the assigned architectures + paper-native problems.
 
 ``get_config(name)`` returns the full ArchConfig; ``windowed_variant``
 produces the sliding-window long-context variant used by dense archs for the
@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from .base import ArchConfig, BlockSpec, attn_block, mamba_block, \
+from .base import ArchConfig, BlockSpec, YarnScaling, attn_block, mamba_block, \
     mlstm_block, slstm_block
 from .stablelm_12b import CONFIG as _stablelm
 from .qwen2_vl_7b import CONFIG as _qwen2vl
@@ -17,6 +17,7 @@ from .whisper_small import CONFIG as _whisper
 from .starcoder2_3b import CONFIG as _starcoder2
 from .phi3_5_moe_42b import CONFIG as _phi35
 from .deepseek_7b import CONFIG as _deepseek
+from .deepseek_v2_lite import CONFIG as _deepseek_v2_lite
 from .dbrx_132b import CONFIG as _dbrx
 from .xlstm_350m import CONFIG as _xlstm
 from .gemma2_27b import CONFIG as _gemma2
@@ -24,7 +25,7 @@ from .gemma2_27b import CONFIG as _gemma2
 ARCHS: dict[str, ArchConfig] = {
     c.name: c for c in [
         _stablelm, _qwen2vl, _jamba, _whisper, _starcoder2, _phi35,
-        _deepseek, _dbrx, _xlstm, _gemma2,
+        _deepseek, _dbrx, _xlstm, _gemma2, _deepseek_v2_lite,
     ]
 }
 

@@ -11,8 +11,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Sequence, Tuple
 
-__all__ = ["BlockSpec", "ArchConfig", "attn_block", "mamba_block",
-           "mlstm_block", "slstm_block"]
+__all__ = ["BlockSpec", "ArchConfig", "YarnScaling", "attn_block",
+           "mamba_block", "mlstm_block", "slstm_block"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,6 +40,20 @@ def mlstm_block() -> BlockSpec:
 
 def slstm_block() -> BlockSpec:
     return BlockSpec("slstm", mlp=False)
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 sets it:
+    frequencies ramp from interpolated (/factor) to unscaled between the
+    dims that rotate beta_slow and beta_fast times over the original
+    context; the softmax scale gains mscale(factor, mscale_all_dim)^2."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +119,19 @@ class ArchConfig:
     seq_parallel_mlp: bool = False         # O4: Megatron-SP style — keep the
     #     residual stream sequence-sharded over `model` through norms + MLP
     #     (turns TP partial-sum all-reduces into cheap boundary reshards)
+    # --- DeepSeek-V2 (arXiv:2405.04434): MLA and DeepSeekMoE ---
+    kv_lora_rank: int = 0                  # > 0: multi-head latent attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[YarnScaling] = None
+    first_dense_layers: int = 0            # dense layers before the periods
+    moe_d_ff: int = 0                      # expert width (0: d_ff)
+    n_shared_experts: int = 0              # one MLP of n_shared * moe_d_ff
+    moe_renormalize: bool = True           # top-k weights summed to 1
+    moe_balance: str = "switch"            # switch | expert (per sequence)
+    experts_held: int = 0                  # this layer computes experts
+    expert_offset: int = 0                 #   [offset, offset + held); 0: all
     # --- coded data parallelism (the paper's technique; DESIGN §4) ---
     coded_dp_beta: int = 2                 # gradient-coding replication factor
     source: str = ""                       # citation
@@ -114,10 +141,17 @@ class ArchConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def rope_dim(self) -> int:
+        """Dims a rotary table covers: the decoupled key of MLA, else a
+        whole head."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.hd
+
+    @property
     def n_periods(self) -> int:
-        assert self.n_layers % len(self.period) == 0, \
-            f"{self.name}: {self.n_layers} layers not divisible by period {len(self.period)}"
-        return self.n_layers // len(self.period)
+        body = self.n_layers - self.first_dense_layers
+        assert body % len(self.period) == 0, \
+            f"{self.name}: {body} layers not divisible by period {len(self.period)}"
+        return body // len(self.period)
 
     def with_overrides(self, **kw) -> "ArchConfig":
         return dataclasses.replace(self, **kw)
@@ -136,12 +170,25 @@ class ArchConfig:
         if {b.kind for b in period} != kinds:
             period = tuple(dict.fromkeys(
                 [next(b for b in self.period if b.kind == k) for k in sorted(kinds)]))
+        # DeepSeekMoE layers keep an expert share: a router wider than the
+        # experts the layer holds
+        share = bool(self.experts_held or self.n_shared_experts)
+        n_experts = min(self.n_experts, 8 if share else 4)
+        lead = min(self.first_dense_layers, 1)
         return dataclasses.replace(
             self,
-            n_layers=2 * len(period), d_model=128, n_heads=n_heads, n_kv=n_kv,
-            d_ff=256, vocab=512, head_dim=hd, period=tuple(period),
-            n_experts=min(self.n_experts, 4) if self.n_experts else 0,
+            n_layers=lead + 2 * len(period), d_model=128, n_heads=n_heads,
+            n_kv=n_kv, d_ff=256, vocab=512, head_dim=hd, period=tuple(period),
+            n_experts=n_experts,
             top_k=min(self.top_k, 2) if self.top_k else 0,
+            experts_held=n_experts // 2 if share else 0,
+            expert_offset=n_experts // 4 if share else 0,
+            first_dense_layers=lead,
+            kv_lora_rank=32 if self.kv_lora_rank else 0,
+            qk_nope_head_dim=16 if self.kv_lora_rank else 0,
+            qk_rope_head_dim=16 if self.kv_lora_rank else 0,
+            v_head_dim=24 if self.kv_lora_rank else 0,
+            moe_d_ff=64 if self.moe_d_ff else 0,
             n_enc_layers=2 if self.n_enc_layers else 0,
             n_enc_frames=16 if self.n_enc_frames else 0,
             n_patches=8 if self.n_patches else 0,

@@ -65,16 +65,19 @@ def _scores(q, k, scale, cap):
 
 def attention(q, k, v, *, causal: bool, window: Optional[int],
               cap: Optional[float], qpos, kpos, kvalid,
-              chunk: int = 1024, banded: bool = False) -> jax.Array:
+              chunk: int = 1024, banded: bool = False,
+              scale: Optional[float] = None) -> jax.Array:
     """Online-softmax GQA attention.
 
-    q: (B, Sq, H, hd);  k, v: (B, Skv, K, hd);  qpos: (Sq,) int32;
-    kpos, kvalid: (Skv,).  Returns (B, Sq, H, hd) in q.dtype.
+    q, k: (B, Sq|Skv, H|K, hd);  v: (B, Skv, K, hv) (hv may differ from
+    hd, as in MLA);  qpos: (Sq,) int32;  kpos, kvalid: (Skv,).  ``scale``
+    defaults to hd ** -0.5.  Returns (B, Sq, H, hv) in q.dtype.
     """
     B, Sq, H, hd = q.shape
     Skv, K = k.shape[1], k.shape[2]
+    hv = v.shape[-1]
     G = H // K
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     qh = q.reshape(B, Sq, K, G, hd).transpose(0, 2, 3, 1, 4)  # (B,K,G,Sq,hd)
 
     if Skv <= chunk or Skv % chunk:
@@ -83,7 +86,7 @@ def attention(q, k, v, *, causal: bool, window: Optional[int],
         s = jnp.where(m[None, None, None], s, _NEG)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bkgsc,bckh->bkgsh", p, v.astype(jnp.float32))
-        return o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd).astype(q.dtype)
+        return o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hv).astype(q.dtype)
 
     if (banded and window is not None and causal and Sq == Skv
             and Skv >= 4 * window and window % chunk == 0):
@@ -93,7 +96,7 @@ def attention(q, k, v, *, causal: bool, window: Optional[int],
 
     n_chunks = Skv // chunk
     kc = k.reshape(B, n_chunks, chunk, K, hd).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, n_chunks, chunk, K, hd).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, n_chunks, chunk, K, hv).transpose(1, 0, 2, 3, 4)
     kposc = kpos.reshape(n_chunks, chunk)
     kvalc = kvalid.reshape(n_chunks, chunk)
 
@@ -115,10 +118,10 @@ def attention(q, k, v, *, causal: bool, window: Optional[int],
 
     init = (jnp.full((B, K, G, Sq), _NEG, jnp.float32),
             jnp.zeros((B, K, G, Sq), jnp.float32),
-            jnp.zeros((B, K, G, Sq, hd), jnp.float32))
+            jnp.zeros((B, K, G, Sq, hv), jnp.float32))
     (m_run, l_run, acc), _ = jax.lax.scan(body, init, (kc, vc, kposc, kvalc))
     o = acc / jnp.maximum(l_run, 1e-30)[..., None]
-    return o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd).astype(q.dtype)
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hv).astype(q.dtype)
 
 
 def _banded_attention(qh, k, v, *, window, cap, scale, chunk, qpos,
@@ -135,8 +138,9 @@ def _banded_attention(qh, k, v, *, window, cap, scale, chunk, qpos,
     nq = S // chunk
     nb = window // chunk + 1                     # KV blocks per q block
     qb = qh.reshape(B, K, G, nq, chunk, hd)
+    hv = v.shape[-1]
     kb = k.reshape(B, nq, chunk, K, hd)
-    vb = v.reshape(B, nq, chunk, K, hd)
+    vb = v.reshape(B, nq, chunk, K, hv)
     # for q block i, kv blocks i-nb+1 .. i (clamped; out-of-range masked)
     offs = jnp.arange(nq)[:, None] - jnp.arange(nb - 1, -1, -1)[None, :]
     valid_blk = offs >= 0
@@ -157,20 +161,20 @@ def _banded_attention(qh, k, v, *, window, cap, scale, chunk, qpos,
     p = jax.nn.softmax(s.reshape(sh[:-2] + (nb * chunk,)),
                        axis=-1).reshape(sh)
     o = jnp.einsum("bkgiqnc,binckh->bkgiqh", p, vg.astype(jnp.float32))
-    o = o.reshape(B, K, G, S, hd).transpose(0, 3, 1, 2, 4)
-    return o.reshape(B, S, K * G, hd).astype(out_dtype)
+    o = o.reshape(B, K, G, S, hv).transpose(0, 3, 1, 2, 4)
+    return o.reshape(B, S, K * G, hv).astype(out_dtype)
 
 
 class AttnCache(NamedTuple):
     """KV cache for one attention layer (ring buffer when windowed)."""
     k: jax.Array   # (B, C, K, hd)
-    v: jax.Array   # (B, C, K, hd)
+    v: jax.Array   # (B, C, K, hv)
 
 
 def init_kv_cache(B: int, cache_len: int, K: int, hd: int,
-                  dtype) -> AttnCache:
+                  dtype, hv: Optional[int] = None) -> AttnCache:
     return AttnCache(jnp.zeros((B, cache_len, K, hd), dtype),
-                     jnp.zeros((B, cache_len, K, hd), dtype))
+                     jnp.zeros((B, cache_len, K, hv or hd), dtype))
 
 
 def ring_slot_positions(cache_len: int, index) -> tuple[jax.Array, jax.Array]:
@@ -187,15 +191,21 @@ def ring_slot_positions(cache_len: int, index) -> tuple[jax.Array, jax.Array]:
 
 
 def decode_attend(p, x, cache: AttnCache, index, *, cfg, window, cap,
-                  rope_fn, pre: str = "") -> tuple[jax.Array, AttnCache]:
+                  rope_fn, pre: str = "", qkv=None,
+                  scale: Optional[float] = None) -> tuple[jax.Array, AttnCache]:
     """Single-token decode: write (k, v) at slot index % C, attend over cache.
 
     x: (B, 1, d); index: scalar int32 current position. rope_fn(q_or_k, pos)
-    applies rotary for this arch (identity for non-rope archs).
+    applies rotary for this arch (identity for non-rope archs).  ``qkv``,
+    where given, maps x to already rotated (q, k, v) in place of the GQA
+    projections (MLA).
     """
-    q, k_new, v_new = qkv_proj(p, x, pre)
-    q = rope_fn(q, jnp.asarray(index)[None])
-    k_new = rope_fn(k_new, jnp.asarray(index)[None])
+    if qkv is None:
+        q, k_new, v_new = qkv_proj(p, x, pre)
+        q = rope_fn(q, jnp.asarray(index)[None])
+        k_new = rope_fn(k_new, jnp.asarray(index)[None])
+    else:
+        q, k_new, v_new = qkv(x)
     C = cache.k.shape[1]
     slot = jnp.mod(jnp.asarray(index, jnp.int32), C)
     k = jax.lax.dynamic_update_slice_in_dim(cache.k, k_new.astype(cache.k.dtype), slot, axis=1)
@@ -203,5 +213,5 @@ def decode_attend(p, x, cache: AttnCache, index, *, cfg, window, cap,
     kpos, kvalid = ring_slot_positions(C, index + 1)
     o = attention(q, k, v, causal=True, window=window, cap=cap,
                   qpos=jnp.asarray(index, jnp.int32)[None], kpos=kpos,
-                  kvalid=kvalid, chunk=cfg.attn_chunk)
+                  kvalid=kvalid, chunk=cfg.attn_chunk, scale=scale)
     return out_proj(p, o, pre), AttnCache(k, v)

@@ -4,7 +4,10 @@ Decoder-only, MoE, hybrid (attn+mamba), xLSTM, encoder-decoder (whisper) and
 VLM (qwen2-vl) all share the same machinery:
 
   * parameters: descriptor trees (models.common) — one period of blocks,
-    stacked over ``n_periods`` and scanned (DESIGN §5);
+    stacked over ``n_periods`` and scanned (DESIGN §5); leading dense
+    layers (``first_dense_layers``, DeepSeek-V2) are a stack of their own,
+    scanned before the periods;
+  * attention blocks are GQA, or MLA where ``kv_lora_rank`` is set;
   * three execution paths: ``forward`` (full-seq, train), ``prefill``
     (full-seq + cache build), ``decode_step`` (one token + cache);
   * logits are tied to the token embedding.
@@ -14,6 +17,7 @@ the scan layout.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, Optional
@@ -24,11 +28,12 @@ import jax.numpy as jnp
 from ..configs.base import ArchConfig, BlockSpec
 from . import attention as attn
 from . import mamba as mb
+from . import mla
 from . import xlstm as xl
 from .common import (pdef, tree_init, tree_axes, stack_defs, rmsnorm,
                      layernorm, softcap, Dtype)
 from .mlp import mlp_defs, mlp_apply
-from .moe import moe_defs, moe_apply
+from .moe import moe_defs, moe_apply, moe_aux_zeros
 from .rope import rope_angles, mrope_angles, apply_rope, sinusoidal_positions
 
 __all__ = ["param_defs", "init_params", "param_axes", "forward", "prefill",
@@ -55,7 +60,7 @@ def _block_defs(cfg, spec: BlockSpec):
     d = {}
     d.update(_norm_defs(cfg, "norm1"))
     if spec.kind == "attn":
-        d.update(attn.attn_defs(cfg))
+        d.update(mla.mla_defs(cfg) if cfg.kv_lora_rank else attn.attn_defs(cfg))
         if spec.cross_attn:
             d.update(_norm_defs(cfg, "normc"))
             d.update(attn.attn_defs(cfg, cross=True))
@@ -77,6 +82,17 @@ def _block_defs(cfg, spec: BlockSpec):
     return d
 
 
+_LEAD = BlockSpec("attn")      # a leading dense layer
+
+
+def _groups(cfg: ArchConfig):
+    """(params key, block specs) of each scanned stack, in order: the
+    leading dense layers (one spec, stacked over first_dense_layers), then
+    the periods."""
+    lead = [("lead", (_LEAD,))] if cfg.first_dense_layers else []
+    return lead + [("blocks", cfg.period)]
+
+
 def param_defs(cfg: ArchConfig):
     defs: dict = {
         "embed": pdef((cfg.vocab, cfg.d_model), ("vocab", "embed"),
@@ -84,6 +100,9 @@ def param_defs(cfg: ArchConfig):
         "blocks": {str(i): stack_defs(_block_defs(cfg, s), cfg.n_periods)
                    for i, s in enumerate(cfg.period)},
     }
+    if cfg.first_dense_layers:
+        defs["lead"] = {"0": stack_defs(_block_defs(cfg, _LEAD),
+                                        cfg.first_dense_layers)}
     defs.update(_norm_defs(cfg, "final_norm"))
     if cfg.n_enc_layers:
         enc_spec = BlockSpec("attn")
@@ -115,7 +134,8 @@ def _rope_ctx(cfg: ArchConfig, positions: jax.Array,
                             cfg.rope_theta)                # (B, S, half)
     if cfg.learned_pos:  # whisper-style: additive sinusoidal, no rotary
         return None
-    cos, sin = rope_angles(positions, cfg.hd, cfg.rope_theta)  # (S, half)
+    cos, sin = rope_angles(positions, cfg.rope_dim, cfg.rope_theta,
+                           cfg.rope_yarn)                  # (S, half)
     return cos[None], sin[None]
 
 
@@ -131,10 +151,23 @@ def _make_rope_fn(ctx):
 def _attn_full(bp, spec, x, cfg, rope_ctx, causal, want_cache, enc_out,
                cache_len=None):
     """Full-sequence attention sublayer. Returns (delta, cache|None)."""
+    with (jax.named_scope("mla") if cfg.kv_lora_rank
+          else contextlib.nullcontext()):
+        return _attn_full_body(bp, spec, x, cfg, rope_ctx, causal,
+                               want_cache, enc_out, cache_len)
+
+
+def _attn_full_body(bp, spec, x, cfg, rope_ctx, causal, want_cache, enc_out,
+                    cache_len):
     B, S, _ = x.shape
-    q, k, v = attn.qkv_proj(bp, x)
     rope_fn = _make_rope_fn(rope_ctx)
-    q, k = rope_fn(q), rope_fn(k)
+    scale = None
+    if cfg.kv_lora_rank:
+        q, k, v = mla.mla_qkv(bp, x, cfg, rope_fn)
+        scale = mla.softmax_scale(cfg)
+    else:
+        q, k, v = attn.qkv_proj(bp, x)
+        q, k = rope_fn(q), rope_fn(k)
     if cfg.seq_parallel_attn:
         # O2 (§Perf): when heads don't divide the model axis, shard the
         # QUERY SEQUENCE over `model` instead — attention compute stays
@@ -145,7 +178,8 @@ def _attn_full(bp, spec, x, cfg, rope_ctx, causal, want_cache, enc_out,
     valid = jnp.ones((S,), bool)
     o = attn.attention(q, k, v, causal=causal, window=spec.window,
                        cap=cfg.attn_softcap, qpos=pos, kpos=pos, kvalid=valid,
-                       chunk=cfg.attn_chunk, banded=cfg.banded_window)
+                       chunk=cfg.attn_chunk, banded=cfg.banded_window,
+                       scale=scale)
     if cfg.seq_parallel_attn:
         from jax.sharding import PartitionSpec as P
         o = jax.lax.with_sharding_constraint(o, P(None, "model", None, None))
@@ -224,9 +258,15 @@ def _block_decode(bp, spec: BlockSpec, x, cfg, cache, index, rope_decode):
             self_cache, cross_cache = cache
         else:
             self_cache = cache
+        qkv = scale = None
+        if cfg.kv_lora_rank:
+            pos = jnp.asarray(index)[None]
+            qkv = lambda t: mla.mla_qkv(bp, t, cfg,
+                                        lambda r: rope_decode(r, pos))
+            scale = mla.softmax_scale(cfg)
         delta, new_self = attn.decode_attend(
             bp, h, self_cache, index, cfg=cfg, window=spec.window,
-            cap=cfg.attn_softcap, rope_fn=rope_decode)
+            cap=cfg.attn_softcap, rope_fn=rope_decode, qkv=qkv, scale=scale)
         if spec.cross_attn:
             xc = _apply_norm(cfg, bp, "normc", x)
             qc = jnp.einsum("bsd,dhk->bshk", xc, bp["cwq"])
@@ -327,19 +367,24 @@ def forward(params, cfg: ArchConfig, tokens, *, patch_embeds=None,
     S = x.shape[1]
     rope_ctx = _rope_ctx(cfg, jnp.arange(S, dtype=jnp.int32), mrope_positions)
     enc_out = _encode(params, cfg, enc_embeds) if cfg.n_enc_layers else None
-    specs = cfg.period
 
-    def period_body(carry, bps):
-        x, aux = carry
-        for i, spec in enumerate(specs):
-            x, _, aux = _block_full(bps[str(i)], spec, x, cfg, rope_ctx, aux,
-                                    want_cache=False, enc_out=enc_out)
-        return (x, aux), None
+    def group_body(specs):
+        def body(carry, bps):
+            x, aux = carry
+            for i, spec in enumerate(specs):
+                x, _, aux = _block_full(bps[str(i)], spec, x, cfg, rope_ctx,
+                                        aux, want_cache=False,
+                                        enc_out=enc_out)
+            return (x, aux), None
+        return body
 
-    aux0 = {"load_balance": jnp.zeros((), jnp.float32),
-            "router_z": jnp.zeros((), jnp.float32)}
-    (x, aux), _ = jax.lax.scan(_maybe_remat(cfg, period_body), (x, aux0),
-                               params["blocks"])
+    aux = {"load_balance": jnp.zeros((), jnp.float32),
+           "router_z": jnp.zeros((), jnp.float32)}
+    if any(b.moe for b in cfg.period):
+        aux = moe_aux_zeros()
+    for key, specs in _groups(cfg):
+        (x, aux), _ = jax.lax.scan(_maybe_remat(cfg, group_body(specs)),
+                                   (x, aux), params[key])
     return _logits(params, cfg, x), aux
 
 
@@ -353,20 +398,25 @@ def prefill(params, cfg: ArchConfig, tokens, *, patch_embeds=None,
     S = x.shape[1]
     rope_ctx = _rope_ctx(cfg, jnp.arange(S, dtype=jnp.int32), mrope_positions)
     enc_out = _encode(params, cfg, enc_embeds) if cfg.n_enc_layers else None
-    specs = cfg.period
 
-    def period_body(x, bps):
-        caches = []
-        for i, spec in enumerate(specs):
-            x, cache, _ = _block_full(bps[str(i)], spec, x, cfg, rope_ctx, {},
-                                      want_cache=True, enc_out=enc_out,
-                                      cache_len=cache_len)
-            caches.append(cache)
-        return x, tuple(caches)
+    def group_body(specs):
+        def body(x, bps):
+            caches = []
+            for i, spec in enumerate(specs):
+                x, cache, _ = _block_full(bps[str(i)], spec, x, cfg, rope_ctx,
+                                          {}, want_cache=True,
+                                          enc_out=enc_out,
+                                          cache_len=cache_len)
+                caches.append(cache)
+            return x, tuple(caches)
+        return body
 
-    x, caches = jax.lax.scan(period_body, x, params["blocks"])
+    caches = []
+    for key, specs in _groups(cfg):
+        x, c = jax.lax.scan(group_body(specs), x, params[key])
+        caches.append(c)
     logits = _logits(params, cfg, x[:, -1:])
-    return logits, caches
+    return logits, _pack_caches(caches)
 
 
 def decode_step(params, cfg: ArchConfig, token, caches, index, *,
@@ -388,32 +438,60 @@ def decode_step(params, cfg: ArchConfig, token, caches, index, *,
         rope_decode = lambda t, pos=None: t
     else:
         def rope_decode(t, pos):
-            cos, sin = rope_angles(pos, cfg.hd, cfg.rope_theta)
+            cos, sin = rope_angles(pos, cfg.rope_dim, cfg.rope_theta,
+                                   cfg.rope_yarn)
             return apply_rope(t, cos[None], sin[None])
 
-    specs = cfg.period
+    def group_body(specs):
+        def body(x, xs):
+            bps, caches_p = xs
+            new = []
+            for i, spec in enumerate(specs):
+                x, nc = _block_decode(bps[str(i)], spec, x, cfg, caches_p[i],
+                                      index, rope_decode)
+                new.append(nc)
+            return x, tuple(new)
+        return body
 
-    def period_body(x, xs):
-        bps, caches_p = xs
-        new = []
-        for i, spec in enumerate(specs):
-            x, nc = _block_decode(bps[str(i)], spec, x, cfg, caches_p[i],
-                                  index, rope_decode)
-            new.append(nc)
-        return x, tuple(new)
+    groups = _groups(cfg)
+    caches = caches if len(groups) > 1 else (caches,)
+    new_caches = []
+    for (key, specs), c in zip(groups, caches):
+        x, nc = jax.lax.scan(group_body(specs), x, (params[key], c))
+        new_caches.append(nc)
+    return _logits(params, cfg, x), _pack_caches(new_caches)
 
-    x, new_caches = jax.lax.scan(period_body, x, (params["blocks"], caches))
-    return _logits(params, cfg, x), new_caches
+
+def _pack_caches(caches: list):
+    """Per-group caches as the model hands them out: the periods' tuple
+    alone, or (leading layers', periods') where leading layers exist."""
+    return caches[0] if len(caches) == 1 else tuple(caches)
 
 
 def init_caches(cfg: ArchConfig, B: int, cache_len: int):
-    """Zero caches matching prefill's structure (stacked over n_periods)."""
+    """Zero caches matching prefill's structure (stacked over n_periods;
+    a pair (leading layers, periods) where the arch has leading layers)."""
+    groups = []
+    for key, specs in _groups(cfg):
+        n = cfg.first_dense_layers if key == "lead" else cfg.n_periods
+        groups.append(_init_group_caches(cfg, specs, n, B, cache_len))
+    return _pack_caches(groups)
+
+
+def _init_group_caches(cfg: ArchConfig, specs, n: int, B: int,
+                       cache_len: int):
     dt = Dtype.of(cfg.dtype)
     per_pos = []
-    for spec in cfg.period:
+    for spec in specs:
         if spec.kind == "attn":
             C = min(cache_len, spec.window) if spec.window else cache_len
-            c = attn.init_kv_cache(B, C, cfg.n_kv, cfg.hd, dt)
+            if cfg.kv_lora_rank:
+                c = attn.init_kv_cache(
+                    B, C, cfg.n_heads,
+                    cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, dt,
+                    hv=cfg.v_head_dim)
+            else:
+                c = attn.init_kv_cache(B, C, cfg.n_kv, cfg.hd, dt)
             if spec.cross_attn:
                 c = (c, attn.init_kv_cache(B, max(cfg.n_enc_frames, 1),
                                            cfg.n_kv, cfg.hd, dt))
@@ -424,7 +502,7 @@ def init_caches(cfg: ArchConfig, B: int, cache_len: int):
         elif spec.kind == "slstm":
             c = xl.init_slstm_cache(cfg, B, dt)
         per_pos.append(c)
-    stack = lambda t: jnp.broadcast_to(t[None], (cfg.n_periods,) + t.shape)
+    stack = lambda t: jnp.broadcast_to(t[None], (n,) + t.shape)
     return jax.tree.map(stack, tuple(per_pos))
 
 

@@ -26,16 +26,23 @@ def adamw_init(params, dtype=jnp.float32) -> AdamWState:
                       jnp.zeros((), jnp.int32))
 
 
-def global_norm(tree) -> jax.Array:
-    return jnp.sqrt(sum(jnp.vdot(x.astype(jnp.float32),
-                                 x.astype(jnp.float32))
-                        for x in jax.tree.leaves(tree)))
+def global_norm(tree, axis_name=None) -> jax.Array:
+    """L2 norm of every leaf together; with ``axis_name`` (inside
+    ``shard_map``) the leaves are each device's part of the tree, and the
+    squares are summed over the axis."""
+    sq = sum(jnp.vdot(x.astype(jnp.float32), x.astype(jnp.float32))
+             for x in jax.tree.leaves(tree))
+    if axis_name is not None:
+        sq = jax.lax.psum(sq, axis_name)
+    return jnp.sqrt(sq)
 
 
 def adamw_update(grads, state: AdamWState, params, *, lr, b1=0.9, b2=0.95,
-                 eps=1e-8, weight_decay=0.1, clip_norm=1.0):
-    """Returns (new_params, new_state, metrics)."""
-    gnorm = global_norm(grads)
+                 eps=1e-8, weight_decay=0.1, clip_norm=1.0, axis_name=None):
+    """Returns (new_params, new_state, metrics).  ``axis_name``: the
+    arguments are each device's part of the parameters, split over that
+    ``shard_map`` axis; the clipping norm is taken over the whole."""
+    gnorm = global_norm(grads, axis_name)
     scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-9))
     count = state.count + 1
     c1 = 1.0 - b1 ** count.astype(jnp.float32)

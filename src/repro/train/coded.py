@@ -17,6 +17,17 @@ Dataflow per step t (one jitted program after the first step):
     kernels.coded_reduce.coded_combine_call(block, decode) / num_groups
     optim.adamw_update
 
+Given as many devices as workers, the trainer instead places worker i on
+device i (``build_worker_per_chip_step``): ``shard_map`` over a
+``workers`` mesh axis, each device holding its worker's tokens, labels,
+coefficients and decode weight, a replica of the weights and a quarter
+(1/m) of the AdamW moments; its gradient, times its decode weight in f32,
+joins a cross-device reduce-scatter (the decode-weighted combine as a
+collective), AdamW updates the device's part of the weights from its part
+of the sum over ``num_groups``, and an all-gather rebuilds the replica.  No
+(m, P) stack is built, and the weights and moments are donated, so a
+device holds one copy of each.
+
 The per-row cross entropy uses a FIXED denominator (rows * S tokens), not
 the self-normalizing ``lm_loss`` weight sum: gradients stay LINEAR in the
 combine coefficients, so with an exact code the decoded update equals the
@@ -43,12 +54,16 @@ from ..kernels.coded_reduce import coded_combine_call
 from ..obs.metrics import MetricsRegistry
 from ..obs.timing import CompileWatch, block
 from ..obs.trace import span as _obs_span
-from ..optim import adamw_init, adamw_update, cosine_schedule
+from ..optim import AdamWState, adamw_init, adamw_update, cosine_schedule
 from ..runtime.engine import ClusterEngine, FastestK, _policy_k_min
 from ..runtime.strategies import UnsupportedStrategy
 
 __all__ = ["TrainerConfig", "TrainProblem", "build_coded_train_step",
-           "CodedTrainer", "run_coded_sgd"]
+           "build_worker_per_chip_step", "split_opt_state", "CodedTrainer",
+           "run_coded_sgd"]
+
+# counters an expert layer adds to a forward's aux (models/moe.py)
+_MOE_COUNTS = ("moe_routed", "moe_dropped", "moe_peak")
 
 
 @dataclasses.dataclass
@@ -96,6 +111,31 @@ class TrainProblem:
                          f"(have: smoke, 100m)")
 
 
+def _worker_loss(cfg: ArchConfig, rows_per_group: int,
+                 z_loss_weight: float) -> Callable:
+    """(params, tokens, labels, coeff) -> (total, (ce, counts)) of one
+    worker's shard: tokens/labels (g, S), coeff (g,).  ``counts`` holds the
+    expert layers' counters (empty for a dense model)."""
+    if cfg.n_patches or cfg.n_enc_layers:
+        raise UnsupportedStrategy("coded-sgd covers token-only LMs (no patch/encoder "
+                         "modalities in the coded worker layout)")
+    from ..models import transformer as T
+
+    def worker_loss(params, tokens, labels, coeff):
+        logits, aux = T.forward(params, cfg, tokens)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        denom = float(rows_per_group * labels.shape[-1])
+        ce = -(ll * coeff[:, None]).sum() / denom
+        scale = coeff.mean()
+        total = ce + scale * (
+            cfg.router_aux_weight * aux.get("load_balance", 0.0)
+            + z_loss_weight * aux.get("router_z", 0.0))
+        return total, (ce, {k: aux[k] for k in _MOE_COUNTS if k in aux})
+
+    return worker_loss
+
+
 def build_coded_train_step(cfg: ArchConfig, lr_fn: Callable, *,
                            rows_per_group: int, num_groups: int,
                            weight_decay: float = 0.1,
@@ -114,26 +154,10 @@ def build_coded_train_step(cfg: ArchConfig, lr_fn: Callable, *,
     Router aux losses ride along scaled by the mean local coefficient, so
     they pass through the same (unbiased) combine.
     """
-    if cfg.n_patches or cfg.n_enc_layers:
-        raise UnsupportedStrategy("coded-sgd covers token-only LMs (no patch/encoder "
-                         "modalities in the coded worker layout)")
-    from ..models import transformer as T
-
-    def worker_loss(params, tokens, labels, coeff):
-        # tokens/labels (g, S); coeff (g,) — one worker's shard
-        logits, aux = T.forward(params, cfg, tokens)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        denom = float(rows_per_group * labels.shape[-1])
-        ce = -(ll * coeff[:, None]).sum() / denom
-        scale = coeff.mean()
-        total = ce + scale * (
-            cfg.router_aux_weight * aux.get("load_balance", 0.0)
-            + z_loss_weight * aux.get("router_z", 0.0))
-        return total, ce
+    worker_loss = _worker_loss(cfg, rows_per_group, z_loss_weight)
 
     def step(params, opt_state, tokens, labels, coeff, decode):
-        (losses_all, losses_ce), grads = jax.vmap(
+        (losses_all, (losses_ce, counts)), grads = jax.vmap(
             jax.value_and_grad(worker_loss, has_aux=True),
             in_axes=(None, 0, 0, 0))(params, tokens, labels, coeff)
         del losses_all
@@ -153,9 +177,91 @@ def build_coded_train_step(cfg: ArchConfig, lr_fn: Callable, *,
         lr = lr_fn(opt_state.count)
         params, opt_state, om = adamw_update(
             grads, opt_state, params, lr=lr, weight_decay=weight_decay)
-        return params, opt_state, {"loss": loss, "lr": lr, **om}
+        counts = {k: v.sum() for k, v in counts.items()}
+        return params, opt_state, {"loss": loss, "lr": lr, **om, **counts}
 
     return step
+
+
+def _rows(x, n: int):
+    """``x`` flattened, zero-padded to a multiple of ``n`` and cut into
+    ``n`` rows: row i is device i's part."""
+    flat = x.reshape(-1)
+    flat = jnp.pad(flat, (0, -flat.size % n))
+    return flat.reshape(n, -1)
+
+
+def split_opt_state(opt: AdamWState, params, mesh) -> AdamWState:
+    """``opt`` in the worker-per-chip step's layout: each moment leaf
+    flattened and zero-padded as ``_rows`` does, and split over the mesh's
+    ``workers`` axis; the count replicated.  A leaf already in that layout
+    is left as it is."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    n = mesh.shape["workers"]
+    split = NamedSharding(mesh, P("workers"))
+
+    def lay(x, p):
+        if x.shape != (p.size + -p.size % n,):
+            x = _rows(x, n).reshape(-1)
+        return jax.device_put(x, split)
+    moments = lambda t: jax.tree.map(lay, t, params)
+    return AdamWState(moments(opt.m), moments(opt.v),
+                      jax.device_put(opt.count, NamedSharding(mesh, P())))
+
+
+def build_worker_per_chip_step(cfg: ArchConfig, lr_fn: Callable, mesh, *,
+                               rows_per_group: int, num_groups: int,
+                               weight_decay: float = 0.1,
+                               z_loss_weight: float = 1e-3) -> Callable:
+    """The coded step with worker i on device i of ``mesh`` (axis
+    ``workers``, one device per worker); the arguments and results are
+    those of ``build_coded_train_step``, with tokens, labels, coeff and
+    decode split over the axis, the weights and metrics replicated and the
+    AdamW state in ``split_opt_state``'s layout.
+
+    A device computes its worker's gradient, scales it by its decode
+    weight in f32 and joins one reduce-scatter over the axis (named scope
+    ``coded_combine``), which leaves it its part of the sum; divided by
+    ``num_groups`` and cast back to the weights' dtype, that part and the
+    device's part of the moments update its part of the weights (AdamW,
+    the clipping norm taken over all parts), and an all-gather (named
+    scope ``weights_gather``) gives every device the whole again."""
+    from jax.sharding import PartitionSpec as P
+    worker_loss = _worker_loss(cfg, rows_per_group, z_loss_weight)
+    n = mesh.shape["workers"]
+
+    def local(params, opt_state, tokens, labels, coeff, decode):
+        (_, (ce, counts)), grads = jax.value_and_grad(
+            worker_loss, has_aux=True)(params, tokens[0], labels[0], coeff[0])
+        c = decode[0]
+        with jax.named_scope("coded_combine"):
+            grads = jax.tree.map(
+                lambda g: (jax.lax.psum_scatter(
+                    _rows(g.astype(jnp.float32) * c, n), "workers")
+                    / num_groups).astype(g.dtype), grads)
+            loss = jax.lax.psum(c * ce, "workers") / num_groups
+            counts = jax.lax.psum(counts, "workers")
+        i = jax.lax.axis_index("workers")
+        mine = jax.tree.map(lambda p: _rows(p, n)[i], params)
+        lr = lr_fn(opt_state.count)
+        mine, opt_state, om = adamw_update(
+            grads, opt_state, mine, lr=lr, weight_decay=weight_decay,
+            axis_name="workers")
+        with jax.named_scope("weights_gather"):
+            params = jax.tree.map(
+                lambda s, p: jax.lax.all_gather(s, "workers", tiled=True)[
+                    :p.size].reshape(p.shape), mine, params)
+        return params, opt_state, {"loss": loss, "lr": lr, **om, **counts}
+
+    rep, split = P(), P("workers")
+    state = AdamWState(split, split, rep)
+    # check_vma=False: the model's scans (attention chunks, layers) start
+    # from unvarying zero carries that the body makes per-worker, which the
+    # varying-axes check rejects; the outputs are collective results and
+    # what AdamW makes of them, the same on every device
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(rep, state, split, split, split, split),
+                         out_specs=(rep, state, rep), check_vma=False)
 
 
 class CodedTrainer:
@@ -166,10 +272,16 @@ class CodedTrainer:
     and bit-reproducible per engine seed); per-step host time is split into
     compile/execute via ``obs.timing.CompileWatch``; the realized schedule
     lands on the active obs recorder and is kept as ``last_schedule``.
+
+    ``devices``: as many as workers places one worker on each
+    (``build_worker_per_chip_step``; the step donates the weights and
+    state, so a caller keeps no reference to what it passes in); None or
+    one device runs every worker on one device under vmap.
     """
 
     def __init__(self, cfg: ArchConfig, tcfg: TrainerConfig,
-                 engine: ClusterEngine, policy=None, degrade=None):
+                 engine: ClusterEngine, policy=None, degrade=None,
+                 devices=None):
         self.cfg, self.tcfg = cfg, tcfg
         if engine.m != tcfg.m_workers:
             raise ValueError(f"engine has m={engine.m} workers but "
@@ -190,24 +302,62 @@ class CodedTrainer:
                              "active set by construction; see DESIGN.md §15)")
         self.degrade = degrade
         lr_fn = cosine_schedule(tcfg.lr, tcfg.warmup, tcfg.steps)
-        self._step = jax.jit(build_coded_train_step(
-            cfg, lr_fn, rows_per_group=tcfg.rows_per_worker,
-            num_groups=self.code.num_groups))
+        self.mesh = None
+        self._put = jnp.asarray
+        if devices is not None and len(devices) > 1:
+            if len(devices) != tcfg.m_workers:
+                raise ValueError(f"{len(devices)} devices for "
+                                 f"{tcfg.m_workers} workers: give one "
+                                 f"device per worker, or one")
+            from jax.sharding import Mesh, NamedSharding, PartitionSpec
+            self.mesh = Mesh(np.asarray(devices), ("workers",))
+            split = NamedSharding(self.mesh, PartitionSpec("workers"))
+            self._put = lambda a: jax.device_put(a, split)
+            self._step = jax.jit(build_worker_per_chip_step(
+                cfg, lr_fn, self.mesh, rows_per_group=tcfg.rows_per_worker,
+                num_groups=self.code.num_groups), donate_argnums=(0, 1))
+        else:
+            self._step = jax.jit(build_coded_train_step(
+                cfg, lr_fn, rows_per_group=tcfg.rows_per_worker,
+                num_groups=self.code.num_groups))
         self.last_schedule = None
         # ``steps`` run and ``host_syncs``: the points of a step at which
-        # the host waits on the device
+        # the host waits on the device; for an expert model ``moe_routed``
+        # and ``moe_dropped``: assignments to the experts held and those
+        # dropped past capacity, and ``moe_peak``: a row's assignments to
+        # its most loaded held expert, each summed over rows, workers,
+        # layers and steps
         self.metrics = MetricsRegistry()
+
+    def place(self, params, opt=None):
+        """The weights and AdamW state (a fresh one if ``opt`` is None) in
+        the step's layout: as they are on one device; over a mesh, the
+        weights replicated and the state as ``split_opt_state`` lays it."""
+        dtype = jnp.dtype(self.cfg.optstate_dtype)
+        if self.mesh is None:
+            return params, (opt if opt is not None
+                            else adamw_init(params, dtype=dtype))
+        from jax.sharding import NamedSharding, PartitionSpec
+        params = jax.device_put(params,
+                                NamedSharding(self.mesh, PartitionSpec()))
+        if opt is None:
+            split = NamedSharding(self.mesh, PartitionSpec("workers"))
+            n = self.mesh.size
+            zeros = lambda: jax.tree.map(
+                lambda p: jnp.zeros((p.size + -p.size % n,), dtype,
+                                    device=split), params)
+            opt = AdamWState(zeros(), zeros(), jnp.zeros((), jnp.int32))
+        return params, split_opt_state(opt, params, self.mesh)
 
     def init_state(self, key=None):
         from ..models import transformer as T
         key = key if key is not None else jax.random.key(self.tcfg.seed)
-        params = T.init_params(self.cfg, key)
-        opt = adamw_init(params, dtype=jnp.dtype(self.cfg.optstate_dtype))
-        return params, opt
+        return self.place(T.init_params(self.cfg, key))
 
     def run(self, params=None, opt=None, callback: Optional[Callable] = None):
         if params is None:
             params, opt = self.init_state()
+        params, opt = self.place(params, opt)
         tc = self.tcfg
         sched = self.engine.sample_schedule(tc.steps, self.policy,
                                             degrade=self.degrade)
@@ -229,14 +379,26 @@ class CodedTrainer:
                     with CompileWatch() as cw:
                         with _obs_span("train:dispatch"):
                             out = self._step(
-                                params, opt, jnp.asarray(tokens),
-                                jnp.asarray(labels), jnp.asarray(coeff),
-                                jnp.asarray(decode))
+                                params, opt, self._put(tokens),
+                                self._put(labels), self._put(coeff),
+                                self._put(decode))
                         with _obs_span("train:wait"):
                             params, opt, metrics = block(out)
                     with _obs_span("train:readback"):
-                        rec = {"step": t, "loss": float(metrics["loss"]),
-                               "grad_norm": float(metrics["grad_norm"]),
+                        if _MOE_COUNTS[0] in metrics:
+                            # one read of the scalars and the counters
+                            loss, gnorm, *counts = jax.device_get(
+                                [metrics["loss"], metrics["grad_norm"]]
+                                + [metrics[k] for k in _MOE_COUNTS])
+                            for k, n in zip(_MOE_COUNTS, counts):
+                                self.metrics.counter(k).inc(int(n))
+                            reads = 1
+                        else:
+                            loss = float(metrics["loss"])
+                            gnorm = float(metrics["grad_norm"])
+                            reads = 2
+                        rec = {"step": t, "loss": float(loss),
+                               "grad_norm": float(gnorm),
                                "sim_time_s": float(sched.times[t]),
                                "active": int((mask > 0).sum()),
                                "exact": bool(
@@ -246,10 +408,10 @@ class CodedTrainer:
                                "execute_s": cw.execute_s,
                                "compiles": cw.compiles}
                         history.append(rec)
-                    # the host waits on the device at block and at the two
-                    # float reads; FRC decode weights are also read back
+                    # the host waits on the device at block and at each
+                    # read; FRC decode weights are also read back
                     steps.inc()
-                    syncs.inc(3 + isinstance(code_t, FRCode))
+                    syncs.inc(1 + reads + isinstance(code_t, FRCode))
                     with _obs_span("train:callback"):
                         if callback:
                             callback(rec)
