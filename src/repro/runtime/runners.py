@@ -37,7 +37,8 @@ from jax.sharding import Mesh, PartitionSpec
 from repro.core.data_parallel import (EncodedProblem, masked_gradient,
                                       original_objective, prox_l1)
 from repro.core.model_parallel import LiftedProblem
-from repro.kernels.fused_step import fused_enabled, fused_masked_gradient
+from repro.kernels.fused_step import (fused_enabled, fused_masked_gradient,
+                                      kernel_path)
 from repro.obs.trace import span as _obs_span
 
 __all__ = [
@@ -49,12 +50,14 @@ __all__ = [
 ]
 
 
-def _traced_call(name: str, fn, *args, **kw):
+def _traced_call(name: str, fn, *args, kernel: str | None = None, **kw):
     """Dispatch a runner inside the program span ``name``.  The span covers
     the dispatch only and never blocks, recorder or not: the device's
     execute time is the profiler trace's to show, and a recorded run takes
-    the path of an unrecorded one."""
-    with _obs_span(name):
+    the path of an unrecorded one.  ``kernel``, for the runners that take
+    the masked gradient, names its path (``fused_step.kernel_path``) as the
+    span's arg of that name."""
+    with _obs_span(name, **({} if kernel is None else {"kernel": kernel})):
         return fn(*args, **kw)
 
 
@@ -65,13 +68,20 @@ def _traced_call(name: str, fn, *args, **kw):
 def _masked_grad(prob: EncodedProblem, w, mask):
     """The per-step masked gradient: the fused Pallas megakernel
     (``kernels/fused_step.py`` — matvec + erasure + combine in one VMEM
-    pass) when ``fused_enabled()`` (TPU default, ``REPRO_FUSED`` override),
-    the dense-einsum path of ``core.data_parallel`` everywhere else.  The
+    pass; under the batched runners' vmap, one pass for all realizations)
+    when ``fused_enabled()`` (TPU default, ``REPRO_FUSED`` override), the
+    dense-einsum path of ``core.data_parallel`` everywhere else.  The
     branch is trace-time, so each compiled runner bakes in one path."""
     if fused_enabled():
         return fused_masked_gradient(prob.SX, prob.Sy, w, mask,
                                      n=prob.n, beta=prob.beta)
     return masked_gradient(prob, w, mask)
+
+
+def _kernel(prob: EncodedProblem, realizations: int) -> str:
+    """The masked-gradient path of a runner over ``realizations`` iterates
+    a device, for its span's ``kernel`` arg."""
+    return kernel_path(realizations, prob.SX.shape[-1])
 
 
 def _runner_name(base: str) -> str:
@@ -212,7 +222,8 @@ def scan_gd(prob: EncodedProblem, masks: jax.Array, step_size,
     """
     return _traced_call(_runner_name("runner:gd"), _scan_gd, prob, masks,
                         step_size, w0, h=h, eval_every=eval_every,
-                        degrade=_degrade_tuple(degrade))
+                        degrade=_degrade_tuple(degrade),
+                        kernel=_kernel(prob, 1))
 
 
 @partial(jax.jit, static_argnames=("eval_every", "degrade"))
@@ -236,7 +247,8 @@ def scan_prox(prob: EncodedProblem, masks: jax.Array, step_size,
     """Encoded proximal gradient (ISTA, l1) over a mask schedule."""
     return _traced_call(_runner_name("runner:prox"), _scan_prox, prob, masks,
                         step_size, w0, eval_every=eval_every,
-                        degrade=_degrade_tuple(degrade))
+                        degrade=_degrade_tuple(degrade),
+                        kernel=_kernel(prob, 1))
 
 
 # LiftedProblem carries Python callables (phi), so the scan cannot be jitted
@@ -388,10 +400,12 @@ def batched_scan_gd(prob: EncodedProblem, masks: jax.Array, step_size,
     if masks.shape[0] == 1:
         return _traced_call(_runner_name("runner:gd"), _scan_gd_r1, prob,
                             masks, step_size, w0, h=h,
-                            eval_every=eval_every, degrade=degrade)
+                            eval_every=eval_every, degrade=degrade,
+                            kernel=_kernel(prob, 1))
     return _traced_call(_runner_name("runner:batched_gd"), _batched_scan_gd,
                         prob, masks, step_size, w0, h=h,
-                        eval_every=eval_every, degrade=degrade)
+                        eval_every=eval_every, degrade=degrade,
+                        kernel=_kernel(prob, masks.shape[0]))
 
 
 def _batched_prox(prob: EncodedProblem, masks: jax.Array, step_size,
@@ -429,10 +443,12 @@ def batched_scan_prox(prob: EncodedProblem, masks: jax.Array, step_size,
     if masks.shape[0] == 1:
         return _traced_call(_runner_name("runner:prox"), _scan_prox_r1,
                             prob, masks, step_size, w0,
-                            eval_every=eval_every, degrade=degrade)
+                            eval_every=eval_every, degrade=degrade,
+                            kernel=_kernel(prob, 1))
     return _traced_call(_runner_name("runner:batched_prox"),
                         _batched_scan_prox, prob, masks, step_size, w0,
-                        eval_every=eval_every, degrade=degrade)
+                        eval_every=eval_every, degrade=degrade,
+                        kernel=_kernel(prob, masks.shape[0]))
 
 
 @lru_cache(maxsize=8)
@@ -581,7 +597,8 @@ def sharded_scan_gd(prob: EncodedProblem, masks: jax.Array, step_size,
         return w, tr, 1
     fn = _sharded_fn("gd", ndev, h, eval_every, 0, degrade)
     w, tr = _traced_call("runner:sharded_gd", fn, prob, masks,
-                         jnp.asarray(step_size, jnp.float32), w0)
+                         jnp.asarray(step_size, jnp.float32), w0,
+                         kernel=_kernel(prob, masks.shape[0] // ndev))
     return w, tr, ndev
 
 
@@ -597,7 +614,8 @@ def sharded_scan_prox(prob: EncodedProblem, masks: jax.Array, step_size,
         return w, tr, 1
     fn = _sharded_fn("prox", ndev, "l1", eval_every, 0, degrade)
     w, tr = _traced_call("runner:sharded_prox", fn, prob, masks,
-                         jnp.asarray(step_size, jnp.float32), w0)
+                         jnp.asarray(step_size, jnp.float32), w0,
+                         kernel=_kernel(prob, masks.shape[0] // ndev))
     return w, tr, ndev
 
 

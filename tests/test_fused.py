@@ -119,6 +119,181 @@ def test_scan_gd_fused_matches_dense(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# shared-slab body: one pass over SX for every realization of a vmap
+# ---------------------------------------------------------------------------
+
+def _kernels(fn, *args):
+    """(body name, grid) of every pallas_call in ``fn``'s jaxpr."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["jaxpr"].debug_info.func_name,
+                              tuple(eqn.params["grid_mapping"].grid)))
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+                    elif hasattr(getattr(sub, "jaxpr", None), "eqns"):
+                        walk(sub.jaxpr)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _batched_operands(R, m, r, p, seed=0):
+    """Shared SX/Sy, R iterates and R masks: realization 0 has every
+    worker erased (the k clamp), the others keep 1..m workers."""
+    SX, Sy, _, _ = _operands(m, r, p, seed=seed)
+    rng = np.random.default_rng(seed + 1)
+    W = jnp.asarray(rng.standard_normal((R, p)), jnp.float32)
+    masks = np.zeros((R, m), np.float32)
+    for i in range(1, R):
+        masks[i, rng.permutation(m)[:1 + (i * (m - 1)) // (R - 1)]] = 1.0
+    return SX, Sy, W, jnp.asarray(masks)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.max(np.abs(a - b)) / np.max(np.abs(b))
+
+
+@pytest.mark.parametrize("R", [2, 16])
+@pytest.mark.parametrize("m", [4, 32])
+@pytest.mark.parametrize("p", [37, 63])
+def test_shared_body_matches_per_realization_and_dense(R, m, p, monkeypatch):
+    from repro.core.data_parallel import EncodedProblem, masked_gradient
+    import repro.kernels.fused_step as fs
+    r, n = 24, m * 12                      # three row blocks of 8
+    SX, Sy, W, masks = _batched_operands(R, m, r, p)
+    grad = jax.vmap(lambda w, mask: fused_masked_gradient(
+        SX, Sy, w, mask, n=n, beta=2.0, interpret=True, block_rows=8))
+    assert _kernels(grad, W, masks) == [("_shared_body", (1, m, 3))]
+    shared = grad(W, masks)
+    monkeypatch.setattr(fs, "fused_body", lambda *a, **kw: "fused")
+    jax.clear_caches()                      # the rule's trace is cached
+    assert _kernels(grad, W, masks) == [("_fused_body", (R, m, 3))]
+    per = grad(W, masks)
+    jax.clear_caches()
+    prob = EncodedProblem(SX=SX, Sy=Sy, X=SX[0], y=Sy[0], lam=0.0,
+                          beta=2.0, n=n)
+    dense = jax.vmap(lambda w, mask: masked_gradient(prob, w, mask))(W, masks)
+    assert shared.shape == (R, p) and shared.dtype == W.dtype
+    assert np.all(np.asarray(shared[0]) == 0.0)        # all erased
+    assert _rel(shared, per) <= 1e-5
+    assert _rel(shared, dense) <= 1e-5
+
+
+def test_batched_sx_keeps_per_realization_grid():
+    """A batched SX (one problem per realization) is no shared slab: the
+    rule keeps the per-realization body with the vmap as a grid axis."""
+    R, m, r, p = 3, 4, 16, 40
+    SX, Sy, W, masks = _batched_operands(R, m, r, p)
+    SXb = jnp.stack([SX * (1.0 + i) for i in range(R)])
+    grad = jax.vmap(lambda sx, w, mask: fused_masked_gradient(
+        sx, Sy, w, mask, n=32, beta=2.0, interpret=True, block_rows=8))
+    assert _kernels(grad, SXb, W, masks) == [("_fused_body", (R, m, 2))]
+    out = grad(SXb, W, masks)
+    for i in range(R):
+        ref = _reference(SXb[i], Sy, W[i], masks[i], n=32, beta=2.0)
+        np.testing.assert_allclose(np.asarray(out[i]), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_unbatched_call_is_todays_body_bitwise():
+    from repro.kernels.fused_step import _fused_call
+    SX, Sy, w, mask = _operands(8, 16, 50)
+    call = lambda SX, Sy, w, mask: fused_masked_gradient(
+        SX, Sy, w, mask, n=64, beta=2.0, interpret=True, block_rows=8)
+    assert _kernels(call, SX, Sy, w, mask) == [("_fused_body", (8, 2))]
+    k = jnp.maximum(mask.sum(), 1.0)
+    c = (mask * (8 / k) / (64 * 2.0)).astype(jnp.float32)
+    direct = _fused_call(SX, Sy, w, c, interpret=True, block_rows=8)
+    assert np.array_equal(np.asarray(call(SX, Sy, w, mask)),
+                          np.asarray(direct))
+
+
+@pytest.mark.parametrize("R,p,body", [(16, 6000, "fused-shared"),
+                                      (64, 6000, "fused-shared"),
+                                      (1, 6000, "fused"),
+                                      (1031, 6000, "fused")])
+def test_fused_body_choice(R, p, body):
+    """One iterate, or a realization count with no block that fits VMEM
+    (a large prime), takes the per-realization body."""
+    from repro.kernels.fused_step import fused_body
+    assert fused_body(R, p) == body
+    assert fused_body(R, p, shared=False) == "fused"
+
+
+def test_pick_fused_block_rows_budgets_shared_residents():
+    """At the ridge cell's shape the slab, its f32 and bf16 copies and the
+    (R, p) W and accumulator all fit the shared body's budget."""
+    from repro.kernels.fused_step import _SHARED_VMEM_BUDGET
+    r, p, R = 256, 6000, 16
+    br = pick_fused_block_rows(r, p, 4, _SHARED_VMEM_BUDGET, realizations=R)
+    assert r % br == 0 and br % 128 == 0
+    assert 2 * br * p * 4 + br * p * 10 + 4 * R * p * 4 <= _SHARED_VMEM_BUDGET
+    assert pick_fused_block_rows(r, p, 4, _SHARED_VMEM_BUDGET,
+                                 realizations=64) < br
+
+
+def _runner_problem():
+    spec = ProblemSpec.synthetic(128, 40, noise=0.5, lam=0.05, seed=5)
+    prob = make_encoded_problem(spec.X, spec.y,
+                                pad_rows(hadamard_encoder(128, 2.0), 8), 8,
+                                lam=spec.lam)
+    eng = ClusterEngine(bimodal_delays(), 8, seed=2)
+    masks = jnp.asarray(np.stack([
+        eng.sample_schedule(12, FastestK(5), realization=i).masks
+        for i in range(4)]))
+    return prob, masks
+
+
+@pytest.mark.parametrize("runner,degrade", [
+    ("batched_scan_gd", None), ("batched_scan_prox", None),
+    ("batched_scan_gd", ("hold", 5, 0.5))])
+def test_batched_runners_shared_body_match_dense(runner, degrade,
+                                                 monkeypatch):
+    """R=4 realizations through the vmapped runners: the shared-slab body
+    (REPRO_FUSED=1) against the dense einsum path.  Every fourth round
+    loses two workers, which the hold-degrade step holds through."""
+    from repro.runtime import runners
+    prob, masks = _runner_problem()
+    masks = masks.at[:, 2::4, :].multiply(
+        jnp.asarray(np.arange(8) >= 2, jnp.float32))
+    assert bool((masks.sum(-1) < 5).any())
+    run = getattr(runners, runner)
+    out = {}
+    for flag in ("0", "1"):
+        monkeypatch.setenv("REPRO_FUSED", flag)
+        jax.clear_caches()
+        w, tr = run(prob, masks, 0.05, jnp.zeros((4, 40)), degrade=degrade)
+        out[flag] = (np.asarray(w), np.asarray(tr))
+    jax.clear_caches()
+    assert _rel(out["1"][0], out["0"][0]) <= 1e-5
+    assert _rel(out["1"][1], out["0"][1]) <= 1e-5
+
+
+@pytest.mark.parametrize("flag,R,path", [("1", 16, "fused-shared"),
+                                         ("1", 1, "fused"),
+                                         ("0", 16, "dense")])
+def test_runner_span_names_kernel_path(flag, R, path, monkeypatch):
+    from repro.obs.trace import TraceRecorder
+    from repro.runtime import runners
+    prob, masks = _runner_problem()
+    masks = jnp.tile(masks, (4, 1, 1))[:R]
+    monkeypatch.setenv("REPRO_FUSED", flag)
+    jax.clear_caches()
+    rec = TraceRecorder()
+    with rec.activate():
+        runners.batched_scan_gd(prob, masks, 0.05, jnp.zeros((R, 40)))
+    jax.clear_caches()
+    (ev,) = rec.spans()
+    assert ev.args["kernel"] == path
+
+
+# ---------------------------------------------------------------------------
 # R == 1 routes through the single-trial scan
 # ---------------------------------------------------------------------------
 

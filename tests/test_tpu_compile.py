@@ -95,3 +95,25 @@ def test_batched_gd_runner_compiles_with_kernel(one_chip, monkeypatch):
     text = _compiled_text(run, one_chip, (M, R, P), (M, R), (4096, P),
                           (4096,), (4, 100, M), (4, P))
     assert "tpu_custom_call" in text
+
+
+def test_batched_gd_runner_shares_slab_at_ridge_cell(one_chip, monkeypatch):
+    """The ridge cell's solve (16 realizations x 100 steps over the paper
+    deployment's SX) lowers the shared-slab body: one kernel call an
+    iteration, its output the whole (16, p) batch, within scoped VMEM."""
+    import repro.kernels.fused_step as fused_step
+    from repro.core.data_parallel import EncodedProblem
+    from repro.runtime.runners import _batched_gd
+    monkeypatch.setenv("REPRO_FUSED", "1")
+    monkeypatch.setattr(fused_step, "default_interpret", lambda: False)
+
+    def run(SX, Sy, X, y, masks, w0):
+        prob = EncodedProblem(SX=SX, Sy=Sy, X=X, y=y, lam=0.05, beta=2.0,
+                              n=4096)
+        return _batched_gd(prob, masks, 1e-3, w0)
+
+    text = _compiled_text(run, one_chip, (M, R, P), (M, R), (4096, P),
+                          (4096,), (16, 100, M), (16, P))
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(kernels) == 1
+    assert f"f32[16,{P}]" in kernels[0].split("custom-call(")[0]
